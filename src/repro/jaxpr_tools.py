@@ -5,7 +5,7 @@ are read off the traced program, so they hold on any backend.
 """
 from __future__ import annotations
 
-from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # RNG primitives whose param-sized outputs would mean a materialized
 # noise tensor (jax.random.uniform lowers to these under jit).
@@ -53,15 +53,12 @@ def pallas_eqns(jaxpr):
 
 
 def pallas_kernel_names(jaxpr):
-    """Best-effort kernel-function name per pallas_call eqn (e.g.
-    '_flash_kernel', '_flash_dq_kernel'), read from the eqn's
-    name_and_src_info (newer JAX) or name param."""
-    names = []
-    for eqn in pallas_eqns(jaxpr):
-        info = eqn.params.get("name_and_src_info")
-        name = getattr(info, "name", None) or eqn.params.get("name") or ""
-        names.append(name)
-    return names
+    """Kernel-function name per pallas_call eqn (e.g. '_flash_kernel',
+    '_flash_dq_kernel'): the call's explicit ``name`` if one was given,
+    else the traced kernel body's function name."""
+    return [eqn.params.get("name")
+            or eqn.params["jaxpr"].debug_info.func_name
+            for eqn in pallas_eqns(jaxpr)]
 
 
 def count_pallas_calls(jaxpr, name_substr: str = "") -> int:
@@ -86,8 +83,8 @@ def pallas_block_shapes(jaxpr):
     outputs, same order as ``pallas_eqns``). With tail masking the chosen
     block must equal min(requested, dim) — reading it off the traced
     program pins the no-whole-dim-fallback contract on any backend."""
-    return [[tuple(bm.block_shape) for bm in
-             eqn.params["grid_mapping"].block_mappings]
+    return [[tuple(getattr(b, "block_size", b) for b in bm.block_shape)
+             for bm in eqn.params["grid_mapping"].block_mappings]
             for eqn in pallas_eqns(jaxpr)]
 
 

@@ -15,10 +15,13 @@ through VMEM **once** and, per tile:
     range-derived FLs arrive per-call via SMEM): round-to-nearest quantizes
     the tile in-register and bins it into row 1+t,
 
-with binning done MXU-style as a one-hot (elements × bins) matmul-reduce
-exactly like ``kl_hist`` — no scatters anywhere. The live resolution r^l
-(runtime, SMEM) masks down the static r_upr-bin buffer; padding lanes are
-masked by global element index so every histogram is exact. One launch
+with binning done as compares, no scatters anywhere: each 128-lane row of
+the tile is tested against a column of bin ids (bins down the sublanes),
+the hits accumulate in a (bins, 128) register tile across the rows, and one
+lane reduction per histogram per tile folds them into the counts. The live
+resolution r^l (runtime, SMEM) masks down the static r_upr-bin buffer;
+padding lanes are masked by global element index so every histogram is
+exact. One launch
 replaces 18 quantize+histogram round trips; the KL/argmin epilogue over the
 (T+1, r_upr) counts is O(T·r_upr) scalar work.
 """
@@ -39,7 +42,7 @@ LANE = 128
 
 
 def _edf_ladder_kernel(scal_ref, meta_ref, fls_ref, x_ref, o_ref, acc_ref, *,
-                       wl_ladder: tuple, r_upr: int, nsteps: int,
+                       wl_ladder: tuple, r_pad: int, nsteps: int,
                        block_rows: int, cols: int):
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -50,28 +53,33 @@ def _edf_ladder_kernel(scal_ref, meta_ref, fls_ref, x_ref, o_ref, acc_ref, *,
     rf = meta_ref[0, 0].astype(jnp.float32)   # live bin count r^l
     n = meta_ref[0, 1]                        # valid element count
     span = jnp.maximum(hi - lo, 1e-12)
-    bins = jax.lax.broadcasted_iota(jnp.float32, (1, r_upr), 1)
-
+    # bin ids down the sublanes, tile elements along the lanes: each row
+    # of the tile compares against every bin with no relayout (Mosaic has
+    # no lane→sublane reshape). int32 iota: Mosaic builds no f32 iota.
+    bins = jax.lax.broadcasted_iota(jnp.int32, (r_pad, 1),
+                                    0).astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
     row0 = pl.program_id(0) * block_rows
-    r = jax.lax.broadcasted_iota(jnp.int32, (block_rows, cols), 0)
-    c = jax.lax.broadcasted_iota(jnp.int32, (block_rows, cols), 1)
-    valid = (((row0 + r) * cols + c) < n).astype(jnp.float32).reshape(-1, 1)
 
-    x = x_ref[...].astype(jnp.float32)
+    def count(quant):
+        """(r_pad, 1) counts of one histogram over this tile."""
+        def row(r, acc):
+            v = quant(x_ref[pl.ds(r, 1), :].astype(jnp.float32))  # (1, cols)
+            # same expression order as pushdown._histogram for bit parity
+            idx = jnp.clip(jnp.floor((v - lo) / span * rf), 0, rf - 1)
+            valid = (row0 + r) * cols + lane < n
+            return acc + jnp.where((idx == bins) & valid, 1.0, 0.0)
 
-    def count(v):
-        # same expression order as pushdown._histogram for bit parity
-        idx = jnp.clip(jnp.floor((v - lo) / span * rf),
-                       0, rf - 1).astype(jnp.float32).reshape(-1, 1)
-        onehot = (idx == bins).astype(jnp.float32) * valid
-        return jnp.sum(onehot, axis=0)
+        acc = jax.lax.fori_loop(0, block_rows, row,
+                                jnp.zeros((r_pad, cols), jnp.float32))
+        return jnp.sum(acc, axis=1, keepdims=True)
 
-    acc_ref[0, :] += count(x)
+    acc_ref[0] += count(lambda v: v)
     for t, wl in enumerate(wl_ladder):        # static unroll over the ladder
         scale = _pow2i(fls_ref[0, t])   # exact: exp2 is off an ulp at FL≳10
         qmax = float(2.0 ** (wl - 1) - 1.0)
-        q = jnp.clip(jnp.round(x * scale), -qmax - 1.0, qmax) / scale
-        acc_ref[1 + t, :] += count(q)
+        acc_ref[1 + t] += count(lambda v, scale=scale, qmax=qmax: jnp.clip(
+            jnp.round(v * scale), -qmax - 1.0, qmax) / scale)
 
     @pl.when(pl.program_id(0) == nsteps - 1)
     def _done():
@@ -106,11 +114,12 @@ def edf_ladder_hists(w: Array, fls: Array, r: Array, *, wl_ladder: tuple,
     fls2 = fls.astype(jnp.int32).reshape(1, -1)
     T = len(wl_ladder)
 
+    r_pad = pl.cdiv(r_upr, 8) * 8               # whole sublane tiles
     grid = (pl.cdiv(rows, block_rows),)
     kernel = functools.partial(_edf_ladder_kernel, wl_ladder=wl_ladder,
-                               r_upr=r_upr, nsteps=grid[0],
+                               r_pad=r_pad, nsteps=grid[0],
                                block_rows=block_rows, cols=cols)
-    return pl.pallas_call(
+    hists = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -119,8 +128,9 @@ def edf_ladder_hists(w: Array, fls: Array, r: Array, *, wl_ladder: tuple,
             pl.BlockSpec(memory_space=pltpu.SMEM),      # per-candidate FLs
             pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1 + T, r_upr), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1 + T, r_upr), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1 + T, r_upr), jnp.float32)],
+        out_specs=pl.BlockSpec((1 + T, r_pad, 1), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1 + T, r_pad, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1 + T, r_pad, 1), jnp.float32)],
         interpret=interpret,
     )(scal, meta, fls2, w2)
+    return hists[:, :r_upr, 0]

@@ -38,12 +38,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 from repro.kernels.fxp_matmul import _clamp_block, _mask_tail
 
 Array = jax.Array
 
 NEG_INF = -1e30
+LANE = 128
+
+
+# The per-row lse and D live in HBM as (B, H, 1, Sq) rows, so their blocks
+# (1, bq) meet the TPU tiling rule for any head count. Inside the kernels
+# they are (bq, 1) columns; these two move between the layouts through one
+# aligned (bq, 128) transpose.
+def _col_to_row(c: Array) -> Array:
+    return jnp.transpose(jnp.broadcast_to(c, (c.shape[0], LANE)))[:1]
+
+
+def _row_to_col(r: Array) -> Array:
+    return jnp.transpose(jnp.broadcast_to(r, (LANE, r.shape[1])))[:, :1]
 
 
 def _positions(iq: int, ik: int, bq: int, bk: int, q_offset: int):
@@ -135,8 +147,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs,
         o_ref[0, 0] = jnp.where(dead, 0.0,
                                 acc_ref[...] / l).astype(o_ref.dtype)
         if lse_ref is not None:
-            lse_ref[0, 0] = jnp.where(dead, NEG_INF,
-                                      m_ref[...] + jnp.log(l))[:, 0]
+            lse_ref[0, 0] = _col_to_row(
+                jnp.where(dead, NEG_INF, m_ref[...] + jnp.log(l)))
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
@@ -181,8 +193,9 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     out_shape = [jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype)]
     out_specs = [pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))]
     if return_lse:
-        out_shape.append(jax.ShapeDtypeStruct((B, H, Sq), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, 1, bq),
+                                      lambda b, h, i, j: (b, h, 0, i)))
 
     out = pl.pallas_call(
         kernel,
@@ -202,12 +215,12 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(qt, kt, vt)
     o = out[0].transpose(0, 2, 1, 3)
-    return (o, out[1]) if return_lse else o
+    return (o, out[1].reshape(B, H, Sq)) if return_lse else o
 
 
 # ---------------------------------------------------------------------------
@@ -217,10 +230,11 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
 def _block_probs(q, k, lse, iq, ik, *, scale, causal, window, softcap,
                  bq, bk, q_offset, sq, skv):
     """Recompute the (bq, bk) probability block p = exp(t − lse) from the
-    stashed logsumexp, plus the pre-mask softcapped logits t (needed for
-    the tanh chain). Masked entries — including q/k tail lanes of partial
-    boundary blocks — are exactly 0 (no NEG_INF arithmetic, so fully-
-    masked rows can't poison the accumulators with inf·0). Callers must
+    stashed logsumexp (a (bq, 1) column), plus the pre-mask softcapped
+    logits t (needed for the tanh chain). Masked entries — including q/k
+    tail lanes of partial boundary blocks — are exactly 0 (no NEG_INF
+    arithmetic, so fully-masked rows can't poison the accumulators with
+    inf·0). Callers must
     hand in tail-sanitized q/k so t itself stays finite (the softcap tanh
     chain multiplies by (1 − (t/cap)²) AFTER the p zeros are in place)."""
     s = jax.lax.dot_general(
@@ -229,7 +243,7 @@ def _block_probs(q, k, lse, iq, ik, *, scale, causal, window, softcap,
     t = softcap * jnp.tanh(s / softcap) if softcap > 0.0 else s
     mask = _block_mask(iq, ik, bq=bq, bk=bk, causal=causal, window=window,
                        q_offset=q_offset, sq=sq, skv=skv)
-    p = jnp.where(mask, jnp.exp(t - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(t - lse), 0.0)
     return p, t
 
 
@@ -260,10 +274,11 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
     k = _mask_tail(k_ref[0, 0].astype(jnp.float32), 0, ik, skv)
     v = _mask_tail(v_ref[0, 0].astype(jnp.float32), 0, ik, skv)
     do = _mask_tail(do_ref[0, 0].astype(jnp.float32), 0, iq, sq)
-    delta = _mask_tail(d_ref[0, 0][:, None], 0, iq, sq)
-    p, t = _block_probs(q, k, lse_ref[0, 0], iq, ik, scale=scale,
-                        causal=causal, window=window, softcap=softcap,
-                        bq=bq, bk=bk, q_offset=q_offset, sq=sq, skv=skv)
+    delta = _mask_tail(_row_to_col(d_ref[0, 0]), 0, iq, sq)
+    p, t = _block_probs(q, k, _row_to_col(lse_ref[0, 0]), iq, ik,
+                        scale=scale, causal=causal, window=window,
+                        softcap=softcap, bq=bq, bk=bk, q_offset=q_offset,
+                        sq=sq, skv=skv)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     g = _grad_wrt_logits(p, dp, delta, t, softcap=softcap)
@@ -301,10 +316,11 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
     k = _mask_tail(k_ref[0, 0].astype(jnp.float32), 0, ik, skv)
     v = _mask_tail(v_ref[0, 0].astype(jnp.float32), 0, ik, skv)
     do = _mask_tail(do_ref[0, 0].astype(jnp.float32), 0, iq, sq)
-    delta = _mask_tail(d_ref[0, 0][:, None], 0, iq, sq)
-    p, t = _block_probs(q, k, lse_ref[0, 0], iq, ik, scale=scale,
-                        causal=causal, window=window, softcap=softcap,
-                        bq=bq, bk=bk, q_offset=q_offset, sq=sq, skv=skv)
+    delta = _mask_tail(_row_to_col(d_ref[0, 0]), 0, iq, sq)
+    p, t = _block_probs(q, k, _row_to_col(lse_ref[0, 0]), iq, ik,
+                        scale=scale, causal=causal, window=window,
+                        softcap=softcap, bq=bq, bk=bk, q_offset=q_offset,
+                        sq=sq, skv=skv)
     dv_acc[...] += jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -350,10 +366,12 @@ def flash_attention_bwd(q: Array, k: Array, v: Array, o: Array, lse: Array,
     vt = v.transpose(0, 2, 1, 3)
     dot = do.transpose(0, 2, 1, 3)
     delta = jnp.sum(dot.astype(jnp.float32)
-                    * o.transpose(0, 2, 1, 3).astype(jnp.float32), axis=-1)
+                    * o.transpose(0, 2, 1, 3).astype(jnp.float32),
+                    axis=-1)[:, :, None, :]                 # (B, H, 1, Sq)
+    lse = lse.reshape(B, H, 1, Sq)
 
     qspec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
-    lspec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i))
+    lspec = pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i))
 
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, scale=sc, causal=causal,
@@ -372,7 +390,7 @@ def flash_attention_bwd(q: Array, k: Array, v: Array, o: Array, lse: Array,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(qt, kt, vt, dot, lse, delta)
@@ -385,8 +403,8 @@ def flash_attention_bwd(q: Array, k: Array, v: Array, o: Array, lse: Array,
         return h * r + j // n
     qjspec = pl.BlockSpec((1, 1, bq, D),
                           lambda b, h, i, j: (b, _qh(h, j), j % nq, 0))
-    ljspec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, _qh(h, j),
-                                                          j % nq))
+    ljspec = pl.BlockSpec((1, 1, 1, bq),
+                          lambda b, h, i, j: (b, _qh(h, j), 0, j % nq))
     kvjspec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, i, 0))
     dkv_out = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, i, 0))
     dk, dv = pl.pallas_call(
@@ -402,7 +420,7 @@ def flash_attention_bwd(q: Array, k: Array, v: Array, o: Array, lse: Array,
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(qt, kt, vt, dot, lse, delta)

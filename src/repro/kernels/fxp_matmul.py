@@ -44,7 +44,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import sr_quantize as _sq
-from repro.kernels._compat import tpu_compiler_params
 
 Array = jax.Array
 
@@ -137,7 +136,7 @@ def fxp_matmul(x: Array, wq: Array, scale: Array, *, bm: int = 256,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, wq, scale.reshape(1, 1).astype(jnp.float32))
 
@@ -191,7 +190,7 @@ def int8_matmul(xq: Array, wq: Array, sx: Array, sw: Array, *, bm: int = 256,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(xq, wq, s)
 
@@ -251,7 +250,7 @@ def matmul_dx(dy: Array, wq: Array, scale: Array, *, bm: int = 256,
         out_shape=jax.ShapeDtypeStruct((M, K), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(dy, wq, scale.reshape(1, 1).astype(jnp.float32))
 
@@ -299,7 +298,7 @@ def matmul_dw(x: Array, dy: Array, *, bm: int = 256, bn: int = 256,
         out_shape=jax.ShapeDtypeStruct((K, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, dy)
 
@@ -400,7 +399,7 @@ def fxp_qmatmul(x: Array, w: Array, seed: Array, fl: Array, mode: Array, *,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(ctl, x, w)
 
@@ -463,7 +462,7 @@ def matmul_qdx(dy: Array, w: Array, seed: Array, fl: Array, mode: Array, *,
         out_shape=jax.ShapeDtypeStruct((M, K), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(ctl, dy, w)
 

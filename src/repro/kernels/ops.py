@@ -69,9 +69,9 @@ def _fused_sharded(x: Array, seed: Array, extras, extra_lead, call,
 
     in_specs = (xspec, P()) + tuple(lead if is_lead else P()
                                     for is_lead in extra_lead)
-    # Manual over the WHOLE mesh (partial-manual shard_map only lowers
-    # under jit on the pinned jaxlib; full-manual also runs eagerly) —
-    # unnamed axes simply see replicated blocks.
+    # Manual over the WHOLE mesh (GSPMD refuses to partition a Mosaic
+    # kernel along any auto axis; full-manual also runs eagerly) — unnamed
+    # axes simply see replicated blocks.
     return shd.shard_map(body, mesh, axis_names=set(mesh.axis_names),
                          in_specs=in_specs, out_specs=xspec)(x, seed, *extras)
 

@@ -58,9 +58,12 @@ def _pow2i(e: Array) -> Array:
     ``exp(e·ln2)``, which is off by an ulp for |e| ≳ 10 — enough to knock
     the ⟨WL,FL⟩ grid off its exact powers of two; the quantize kernels must
     never be. In-kernel mirror of ``core.fixed_point.pow2i`` (the kernels
-    stay import-free of core)."""
+    stay import-free of core). ``e`` is a scalar read from SMEM; the result
+    is a (1, 1) vector because Mosaic bitcasts vectors only — it
+    broadcasts against any tile."""
     e = jnp.clip(e.astype(jnp.int32), -126, 127)
-    return jax.lax.bitcast_convert_type((e + 127) << 23, jnp.float32)
+    bits = jnp.broadcast_to((e + 127) << 23, (1, 1))
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
 def _sr_quantize_kernel(wlfl_ref, x_ref, u_ref, o_ref):
@@ -124,7 +127,15 @@ def uniform_from_index(seed: Array, idx: Array) -> Array:
     h ^= h >> 15
     h = h * jnp.uint32(0x846CA68B)
     h ^= h >> 16
-    return (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    return _top24_uniform(h)
+
+
+def _top24_uniform(h: Array) -> Array:
+    """U[0,1) from the top 24 bits of uint32 ``h``. Mosaic has no
+    uint32→f32 cast; the 24-bit value is below 2^24, so going through
+    int32 is exact and leaves the stream bit-identical."""
+    return ((h >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 def _hash_uniform(seed: Array, shape, row0: Array, cols: int) -> Array:
@@ -140,19 +151,19 @@ def _hash_uniform(seed: Array, shape, row0: Array, cols: int) -> Array:
     return uniform_from_index(seed, idx)
 
 
-def _hw_uniform(seed: Array, shape, block_ids) -> Array:
-    # Distinct hardware stream per ⟨seed, block ids⟩; reseeding per block
-    # keeps the stream independent of the grid schedule.
-    pltpu.prng_seed(seed, *block_ids)
+def _hw_uniform(seed: Array, shape, block_id) -> Array:
+    # Distinct hardware stream per ⟨seed, linear block id⟩ (Mosaic takes at
+    # most two seed words); reseeding per block keeps the stream
+    # independent of the grid schedule.
+    pltpu.prng_seed(seed, block_id)
     bits = pltpu.prng_random_bits(shape)
-    u32 = pltpu.bitcast(bits, jnp.uint32)
-    return (u32 >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    return _top24_uniform(pltpu.bitcast(bits, jnp.uint32))
 
 
 def _inkernel_uniform(seed: Array, shape, block_rows: int, cols: int,
                       hw_prng: bool) -> Array:
     if hw_prng:
-        return _hw_uniform(seed, shape, (pl.program_id(0),))
+        return _hw_uniform(seed, shape, pl.program_id(0))
     row0 = pl.program_id(0) * block_rows
     return _hash_uniform(seed, shape, row0, cols)
 
@@ -271,7 +282,7 @@ def sr_quantize_fused_int8(x: Array, seed: Array, fl: Array, *,
 def _stacked_uniform(seed: Array, shape, l, blk, block_rows: int, cols: int,
                      rows: int, hw_prng: bool) -> Array:
     if hw_prng:
-        return _hw_uniform(seed, shape, (l, blk))
+        return _hw_uniform(seed, shape, l * pl.num_programs(1) + blk)
     # Flat index over the padded (L·rows, cols) stack: layer l's stream
     # starts at row l·rows, so L=1 degenerates to the unstacked stream and
     # the bits never depend on block_rows.

@@ -115,7 +115,7 @@ def lower_cell(arch: str, shape: str, *, multi_pod: bool = False,
                                   getattr(mem, "temp_size_in_bytes", 0)),
             }
         from repro.roofline import hlo_costs
-        c = hlo_costs.xla_cost_analysis(compiled)
+        c = compiled.cost_analysis()
         if c:
             # NB: XLA counts while bodies once — kept for reference only;
             # the roofline uses the trip-count-aware walker below.

@@ -23,7 +23,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import Config
 
@@ -31,15 +31,24 @@ from repro.config import Config
 FSDP_THRESHOLD = 128 * 1024 * 1024
 
 
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices=None) -> Mesh:
+    """Mesh with Auto axes: the model code's logical-axis constraints
+    (``sharding.shard``) are GSPMD hints, which only Auto axes accept —
+    ``jax.make_mesh`` defaults to Explicit axes."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_cpu_mesh() -> Mesh:
     """1-device mesh with the same axis names (tests / local smoke)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -100,7 +109,11 @@ def _div(n: int, k: int) -> bool:
 
 
 def _fits(shape, dim: int, n: int) -> bool:
-    return shape[dim] % n == 0 and shape[dim] >= n
+    """Shard ``dim`` over an axis of size ``n``? Never over a size-1 axis:
+    it splits nothing, and naming it would still move the leaf onto the
+    shard_map-wrapped quantize with a folded per-shard seed — a different
+    SR stream from the same weights on one device."""
+    return n > 1 and shape[dim] % n == 0 and shape[dim] >= n
 
 
 def param_pspec(path: str, shape: Tuple[int, ...], cfg: Config, mesh: Mesh,

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import load_config
+from repro.launch.cache import use_compile_cache
 from repro.serve.engine import Engine
 from repro.train import train_loop
 from repro.train.checkpoint import CheckpointManager
@@ -51,6 +52,7 @@ def main(argv=None):
                     help="[continuous] disable the precision policy")
     ap.add_argument("--override", action="append", default=[])
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.smoke:
         from repro.configs import get_smoke_config

@@ -17,6 +17,7 @@ import os
 import jax
 
 from repro.config import load_config
+from repro.launch.cache import use_compile_cache
 from repro.train import train_loop
 from repro.train.checkpoint import CheckpointManager
 from repro.train.fault_tolerance import (Heartbeat, PreemptionGuard,
@@ -36,6 +37,7 @@ def main(argv=None):
                     help="write JSONL step/switch telemetry here")
     ap.add_argument("--override", action="append", default=[])
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if "COORDINATOR_ADDRESS" in os.environ:   # multi-host entry
         jax.distributed.initialize()

@@ -23,11 +23,6 @@ from repro.models import common
 
 Array = jax.Array
 
-# XLA CPU cannot execute batched BF16×BF16→F32 dots (see models/moe.py);
-# upcast there — TPU keeps the bf16 AV contraction.
-_CPU_EXEC = jax.default_backend() == "cpu"
-
-
 def init_layer(key: Array, cfg: ModelConfig, num_layers: int,
                cross: bool = False) -> Dict[str, Array]:
     d, h, hkv = cfg.d_model, cfg.num_heads, cfg.num_kv_heads
@@ -141,9 +136,7 @@ def _masked_attention(q, k, v, qpos, kpos, window, cap, causal):
     p = jax.nn.softmax(logits, axis=-1)
     # probabilities in the input dtype for the AV contraction (what flash
     # kernels do): halves P/V traffic and the f32 dk/dv backward payloads
-    av_dt = jnp.float32 if _CPU_EXEC else v.dtype
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(av_dt), v.astype(av_dt),
-                     preferred_element_type=jnp.float32)
+    out = common.einsum_f32("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
     if pad_to and pad_to > H:
         out = out[:, :, :H, :]
     return out.astype(q.dtype)

@@ -30,6 +30,22 @@ def act_fn(x: Array, kind: str) -> Array:
     return jax.nn.silu(x)
 
 
+def einsum_f32(spec: str, a: Array, b: Array) -> Array:
+    """``jnp.einsum`` with f32 accumulation. XLA's CPU runtime cannot run
+    every BF16×BF16→F32 dot ("DotThunk: unsupported element type"), so a
+    program lowered for the CPU upcasts the operands first — the products
+    of bf16 values are exact in f32 — while the TPU keeps its bf16 MXU
+    path. Decided per lowering, so a program compiled for a TPU from a
+    CPU host takes the TPU branch."""
+    def dot(a, b):
+        return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+    def upcast(a, b):
+        return dot(a.astype(jnp.float32), b.astype(jnp.float32))
+
+    return jax.lax.platform_dependent(a, b, cpu=upcast, default=dot)
+
+
 def dense(x: Array, w, *, out_logical: str | None = None,
           use_pallas: bool = False) -> Array:
     """x @ w with f32 accumulation; annotates the contraction output.
@@ -53,12 +69,12 @@ def dense(x: Array, w, *, out_logical: str | None = None,
     a ~2^-8 relative rounding on a 16-way sum (§Perf lever). The flag
     applies to the PLAIN-array path only: the kernel paths accumulate in
     f32 VMEM scratch and emit x.dtype. NOTE the kernel paths are
-    single-device/replicated constructs — pallas_call has no SPMD
-    partitioning rule. The controller keeps explicitly-sharded leaves off
-    the PROLOGUE format (controller._use_dense_prologue), but a sharded
-    MATERIALIZED packed leaf handed here under use_pallas would still be
-    replicated by GSPMD; shard_map-wrapping the dense kernels is the open
-    ROADMAP item, and no shipped config enables use_pallas on a mesh."""
+    per-device constructs — GSPMD refuses to partition a Mosaic kernel.
+    Data-parallel training runs the whole step per device under
+    ``shard_map`` (``train_loop.data_parallel_step``); the controller
+    refuses explicitly-sharded dense leaves under use_pallas
+    (controller.quantize_params_packed), and shard_map-wrapping the dense
+    kernels for sharded weights is the open ROADMAP item."""
     if isinstance(w, dict):
         y = _dense_quantized(x, w, use_pallas)
     else:

@@ -23,21 +23,6 @@ from repro.models import common
 
 Array = jax.Array
 
-# XLA's CPU thunk runtime cannot execute batched BF16×BF16→F32 dots
-# ("DotThunk: unsupported element type"); TPU MXU handles them natively.
-# On CPU we upcast the expert einsum operands — numerics-identical, and the
-# dry-run (which only compiles) is unaffected on its bytes accounting for
-# TPU targets except a documented ≤2× pessimism on MoE weight bytes.
-_CPU_EXEC = jax.default_backend() == "cpu"
-
-
-def _edot(spec: str, a: Array, b: Array) -> Array:
-    if _CPU_EXEC:
-        a = a.astype(jnp.float32)
-        b = b.astype(jnp.float32)
-    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
-
-
 def init_layer(key: Array, cfg: ModelConfig, num_layers: int) -> Dict[str, Array]:
     d = cfg.d_model
     f = cfg.moe_d_ff or cfg.d_ff
@@ -105,12 +90,14 @@ def apply(p: Dict[str, Array], x: Array, cfg: ModelConfig,
     xin = xin[:, :E * cap].reshape(g, E, cap, D)
     xin = sharding.shard(xin, "batch", "experts", None, None)
 
-    gate = _edot("gecd,edf->gecf", xin, p["we_gate"].astype(x.dtype))
-    up = _edot("gecd,edf->gecf", xin, p["we_up"].astype(x.dtype))
+    gate = common.einsum_f32("gecd,edf->gecf", xin,
+                             p["we_gate"].astype(x.dtype))
+    up = common.einsum_f32("gecd,edf->gecf", xin,
+                           p["we_up"].astype(x.dtype))
     act = (common.act_fn(gate, cfg.act_fn) * up).astype(x.dtype)
     act = sharding.shard(act, "batch", "experts", None, "ff")
-    eout = _edot("gecf,efd->gecd", act,
-                 p["we_down"].astype(x.dtype)).astype(x.dtype)
+    eout = common.einsum_f32("gecf,efd->gecd", act,
+                             p["we_down"].astype(x.dtype)).astype(x.dtype)
     eout = sharding.shard(eout, "batch", "experts", None, None)
 
     eflat = jnp.concatenate(

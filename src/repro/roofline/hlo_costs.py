@@ -275,16 +275,6 @@ class Walker:
             total["coll"][k] = total["coll"].get(k, 0.0) + v * mult
 
 
-def xla_cost_analysis(compiled) -> Optional[Dict]:
-    """``compiled.cost_analysis()`` normalized across JAX versions: older
-    jaxlibs return a one-element list of per-device dicts, newer ones the
-    dict itself. Returns None when XLA provides nothing."""
-    cost = compiled.cost_analysis()
-    if not cost:
-        return None
-    return cost if isinstance(cost, dict) else cost[0]
-
-
 def module_costs(hlo_text: str) -> Dict:
     """Per-chip {flops, bytes, collectives{kind: bytes}, dynamic_loops}."""
     comps = parse_module(hlo_text)

@@ -84,19 +84,11 @@ def named_sharding(*logical_axes: Optional[str]) -> Optional[NamedSharding]:
 
 def shard_map(f, mesh: Mesh, *, axis_names, in_specs, out_specs,
               check: bool = False):
-    """Version-compat shard_map, manual ONLY over ``axis_names`` (auto over
-    the rest of the mesh). Newer JAX spells this ``jax.shard_map(...,
-    axis_names=..., check_vma=...)``; the pinned jaxlib only ships
-    ``jax.experimental.shard_map.shard_map(..., auto=..., check_rep=...)``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, axis_names=set(axis_names),
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check, auto=auto)
+    """``jax.shard_map`` manual ONLY over ``axis_names`` (auto over the rest
+    of the mesh)."""
+    return jax.shard_map(f, mesh=mesh, axis_names=set(axis_names),
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check)
 
 
 def spec_dim_axes(spec, ndim: int) -> Tuple[tuple, ...]:

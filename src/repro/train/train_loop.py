@@ -106,8 +106,13 @@ def _task_loss(cfg: Config, qparams, stats, batch, act_wl=None,
 # Train step
 
 
-def make_train_step(cfg: Config, qparam_shardings=None) -> Callable:
-    """``qparam_shardings``: optional NamedSharding tree for the quantized
+def make_train_step(cfg: Config, qparam_shardings=None,
+                    dp_axes: Tuple[str, ...] = ()) -> Callable:
+    """``dp_axes``: mesh axes of a ``shard_map`` the step runs under, each
+    device on its own batch shard; loss and gradients are averaged over
+    them before the update (``data_parallel_step``).
+
+    ``qparam_shardings``: optional NamedSharding tree for the quantized
     copy. Without it GSPMD may resolve the (sharded master × replicated SR
     noise) elementwise quantize to a REPLICATED output — i.e. all-gather the
     f32 master instead of the small quantized container (measured on
@@ -223,6 +228,8 @@ def make_train_step(cfg: Config, qparam_shardings=None) -> Callable:
                 check=False)(qparams, batch)
         else:
             loss, task, aux, grads = compute_grads(qparams, batch)
+        if dp_axes:       # equal shards: the mean of means is the global mean
+            loss, task, grads = jax.lax.pmean((loss, task, grads), dp_axes)
 
         if qcfg.mode != "off":
             adapt = controller.accumulate(adapt, grads, task)
@@ -293,7 +300,49 @@ def make_batch(cfg: Config, step: int) -> Dict[str, Array]:
 
 
 # ---------------------------------------------------------------------------
-# Host-side driver (single-process; the launcher adds mesh/shardings)
+# Data parallelism over a mesh
+
+
+def data_parallel_step(cfg: Config, mesh, state_shapes, batch_shapes):
+    """The train step and the precision switch, data parallel over
+    ``mesh``: the state is placed by launch/mesh's ``state_shardings`` and
+    must come out replicated (every parameter below ``FSDP_THRESHOLD`` and
+    no model-axis split), the batch is split over the data axes by
+    ``batch_shardings``. GSPMD cannot partition a Mosaic kernel, so each
+    device runs the whole step on its batch shard inside ``shard_map``
+    (manual over every mesh axis) and the step averages loss and gradients
+    over the data axes; the activation-quantize ranges are per shard.
+    Returns (step, switch, state shardings, batch shardings), jitted with
+    the state donated."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro import sharding
+    from repro.launch import mesh as mesh_lib
+    state_sh = mesh_lib.state_shardings(state_shapes, cfg, mesh)
+    split = [jax.tree_util.keystr(path) for path, s in
+             jax.tree_util.tree_flatten_with_path(state_sh)[0]
+             if not s.is_fully_replicated]
+    if split:
+        raise ValueError(f"data_parallel_step: state leaves {split[:3]} are "
+                         "sharded; data parallelism needs them replicated")
+    batch_sh = mesh_lib.batch_shardings(batch_shapes, mesh)
+    dp = mesh_lib.dp_axes(mesh)
+    axes = set(mesh.axis_names)
+    step = sharding.shard_map(
+        make_train_step(cfg, dp_axes=dp), mesh, axis_names=axes,
+        in_specs=(P(), P(dp)), out_specs=(P(), P()))
+    switch = sharding.shard_map(make_precision_switch(cfg), mesh,
+                                axis_names=axes, in_specs=P(),
+                                out_specs=P())
+    return (jax.jit(step, in_shardings=(state_sh, batch_sh),
+                    out_shardings=(state_sh, None), donate_argnums=0),
+            jax.jit(switch, in_shardings=(state_sh,),
+                    out_shardings=state_sh, donate_argnums=0),
+            state_sh, batch_sh)
+
+
+# ---------------------------------------------------------------------------
+# Host-side driver (one process; data parallel when given a mesh)
 
 
 def train(cfg: Config, *, steps: Optional[int] = None,
@@ -302,10 +351,16 @@ def train(cfg: Config, *, steps: Optional[int] = None,
           log: Callable[[str], None] = print,
           telemetry: Optional[list] = None,
           metrics_logger=None, preemption_guard=None,
-          heartbeat=None) -> Tuple[Dict[str, Any], list]:
+          heartbeat=None, mesh=None,
+          step_fn: Optional[Callable] = None) -> Tuple[Dict[str, Any], list]:
     """Run the loop; returns (state, history). ``telemetry`` (if a list)
     collects per-switch controller snapshots for the paper's perf model;
     ``metrics_logger`` (train.metrics.MetricsLogger) streams JSONL.
+
+    ``mesh``: train data parallel over it (``data_parallel_step``); state
+    and batches are placed on it. ``step_fn``: an already-compiled step
+    (``jax.jit(...).lower(...).compile()`` of the same program) to run in
+    place of the one built here.
 
     ``preemption_guard`` (fault_tolerance.PreemptionGuard): checked after
     every step — a SIGTERM triggers one final checkpoint save (when a
@@ -316,9 +371,18 @@ def train(cfg: Config, *, steps: Optional[int] = None,
     steps = steps if steps is not None else cfg.train.steps
     if state is None:
         state = init_state(cfg)
-    step_fn = jax.jit(make_train_step(cfg), donate_argnums=0)
-    switch_fn = (jax.jit(make_precision_switch(cfg), donate_argnums=0)
-                 if cfg.quant.mode != "off" else None)
+    batch_sh = None
+    if mesh is not None:
+        jitted, switch_fn, state_sh, batch_sh = data_parallel_step(
+            cfg, mesh, jax.eval_shape(lambda: state),
+            jax.eval_shape(lambda: make_batch(cfg, 0)))
+        state = jax.device_put(state, state_sh)
+    else:
+        jitted = jax.jit(make_train_step(cfg), donate_argnums=0)
+        switch_fn = jax.jit(make_precision_switch(cfg), donate_argnums=0)
+    step_fn = step_fn or jitted
+    if cfg.quant.mode == "off":
+        switch_fn = None
     interval = cfg.train.adapt_interval or cfg.quant.lb_lwr
 
     history = []
@@ -326,6 +390,8 @@ def train(cfg: Config, *, steps: Optional[int] = None,
     for i in range(start_step, start_step + steps):
         t0 = time.perf_counter()
         batch = make_batch(cfg, i)
+        if batch_sh is not None:
+            batch = jax.device_put(batch, batch_sh)
         state, metrics = step_fn(state, batch)
         if switch_fn is not None and (i + 1) % interval == 0:
             state = switch_fn(state)

@@ -1,0 +1,167 @@
+"""Described-topology compiles of the main-path kernels at SmolLM-360M widths.
+
+Interpret mode runs every kernel on the CPU, but it accepts programs the TPU
+compiler refuses (unsupported casts, iotas of the wrong dtype, blocks that
+break the (8, 128) tiling rule, too much VMEM). These tests lower each kernel
+of the AdaPT train step with ``interpret=False`` for one chip of a described
+``v5e:2x2`` topology and compile it with the installed TPU compiler — no chip
+is needed, and nothing runs. Widths are SmolLM-360M's (d=960, ff=2560, 15/5
+heads of 64, vocab 49152) at seq 2048 and global batch 8: d_model is not a
+multiple of the 256/512 blocks, so the tail-masked boundary blocks compile too.
+
+The topology is described inside a module fixture: only the worker that runs
+this file loads the TPU compiler library.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.pushdown import WL_LADDER
+from repro.kernels import edf_ladder as el
+from repro.kernels import flash_attention as fa
+from repro.kernels import fxp_matmul as fm
+from repro.kernels import sr_quantize as sq
+
+D, FF, H, HKV, DH, VOCAB, LAYERS = 960, 2560, 15, 5, 64, 49152, 32
+BATCH, SEQ = 8, 2048
+M = BATCH * SEQ
+# (K, N) of every dense layer: q/o projections, k/v projections, MLP in, MLP
+# out, LM head.
+DENSE_SHAPES = [(D, D), (D, HKV * DH), (D, FF), (FF, D), (D, VOCAB)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler library in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """A described-topology compile is written to the persistent cache but
+    cannot be read back without a chip — keep the cache off around these."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    """Compile ``fn`` for the described chip; returns the HLO text after
+    asserting every kernel lowered to a Mosaic custom call."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "kernel did not lower to Mosaic"
+    return text
+
+
+@pytest.mark.parametrize("hw_prng", [True, False])
+@pytest.mark.parametrize("shape", [(D, VOCAB), (VOCAB, D)])
+def test_sr_quantize_fused_int8(one_chip, shape, hw_prng):
+    fn = functools.partial(sq.sr_quantize_fused_int8, interpret=False,
+                           hw_prng=hw_prng)
+    _compile(fn, _spec(one_chip, shape, jnp.float32),
+             _spec(one_chip, (), jnp.int32), _spec(one_chip, (), jnp.int32))
+
+
+@pytest.mark.parametrize("hw_prng", [True, False])
+@pytest.mark.parametrize("kn", [(D, HKV * DH), (FF, D)])
+def test_sr_quantize_fused_stacked_int8(one_chip, kn, hw_prng):
+    fn = functools.partial(sq.sr_quantize_fused_stacked_int8,
+                           interpret=False, hw_prng=hw_prng)
+    _compile(fn, _spec(one_chip, (LAYERS,) + kn, jnp.float32),
+             _spec(one_chip, (), jnp.int32),
+             _spec(one_chip, (LAYERS,), jnp.int32))
+
+
+@pytest.mark.parametrize("per_layer", [False, True])
+def test_edf_ladder_hists(one_chip, per_layer):
+    """The PushDown ladder at the default 65536-element EDF subsample and
+    r_upr = 150; ``per_layer`` vmaps it over the 32 stacked layers, as
+    ``controller.precision_switch`` does."""
+    fn = functools.partial(el.edf_ladder_hists, wl_ladder=WL_LADDER,
+                           r_upr=150, interpret=False)
+    lead = (LAYERS,) if per_layer else ()
+    if per_layer:
+        fn = jax.vmap(fn)
+    _compile(fn, _spec(one_chip, lead + (65536,), jnp.float32),
+             _spec(one_chip, lead + (len(WL_LADDER),), jnp.int32),
+             _spec(one_chip, lead, jnp.int32))
+
+
+@pytest.mark.parametrize("kn", DENSE_SHAPES)
+def test_fxp_dense_vjp_grad(one_chip, kn):
+    K, N = kn
+
+    def loss(x, wq, scale, wref):
+        y = fm.fxp_dense_vjp(x, wq, scale, wref, interpret=False)
+        return jnp.sum(y.astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 3)),
+             _spec(one_chip, (M, K), jnp.bfloat16),
+             _spec(one_chip, (K, N), jnp.int8),
+             _spec(one_chip, (), jnp.float32),
+             _spec(one_chip, (K, N), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("kn", DENSE_SHAPES)
+def test_fxp_qdense_vjp_grad(one_chip, kn):
+    K, N = kn
+
+    def loss(x, w, seed, fl, mode):
+        y = fm.fxp_qdense_vjp(x, w, seed, fl, mode, interpret=False)
+        return jnp.sum(y.astype(jnp.float32))
+
+    i32 = _spec(one_chip, (), jnp.int32)
+    _compile(jax.grad(loss, argnums=(0, 1)),
+             _spec(one_chip, (M, K), jnp.bfloat16),
+             _spec(one_chip, (K, N), jnp.float32), i32, i32, i32)
+
+
+def test_flash_attention_vjp_grad(one_chip):
+    def loss(q, k, v):
+        o = fa.flash_attention_vjp(q, k, v, causal=True, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)),
+             _spec(one_chip, (BATCH, SEQ, H, DH), jnp.bfloat16),
+             _spec(one_chip, (BATCH, SEQ, HKV, DH), jnp.bfloat16),
+             _spec(one_chip, (BATCH, SEQ, HKV, DH), jnp.bfloat16))
+
+
+def test_flash_attention_forward(one_chip):
+    """The serving/prefill forward (no lse output)."""
+    fn = functools.partial(fa.flash_attention, causal=True, interpret=False)
+    _compile(fn, _spec(one_chip, (BATCH, SEQ, H, DH), jnp.bfloat16),
+             _spec(one_chip, (BATCH, SEQ, HKV, DH), jnp.bfloat16),
+             _spec(one_chip, (BATCH, SEQ, HKV, DH), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("kn", [(D, D), (D, VOCAB)])
+def test_fxp_matmul_decode_rows(one_chip, kn):
+    """Serving decode: one row per slot, the four batcher slots vmapped
+    (``ContinuousBatcher._decode_fn``)."""
+    K, N = kn
+    fn = jax.vmap(lambda x, wq, s: fm.fxp_matmul(x, wq, s, interpret=False),
+                  in_axes=(0, None, None))
+    _compile(fn, _spec(one_chip, (4, 1, K), jnp.bfloat16),
+             _spec(one_chip, (K, N), jnp.int8),
+             _spec(one_chip, (), jnp.float32))

@@ -251,9 +251,12 @@ def test_packed_dense_sharded_mesh_refused():
             state["params"], state["adapt"], cfg.quant, key=KEY,
             shardings=shardings)
     # the guard is generic over Sharding types, not a NamedSharding
-    # whitelist — a PositionalSharding distribution must refuse too
-    from jax.sharding import PositionalSharding
-    shardings["head"] = PositionalSharding(jax.devices()[:2]).reshape(2, 1)
+    # whitelist — any other >1-device split placement must refuse too
+    class _SplitSharding:
+        device_set = frozenset(jax.devices()[:2])
+        is_fully_replicated = False
+
+    shardings["head"] = _SplitSharding()
     with pytest.raises(ValueError, match="dense kernel path"):
         controller.quantize_params_packed(
             state["params"], state["adapt"], cfg.quant, key=KEY,

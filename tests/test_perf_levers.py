@@ -14,7 +14,7 @@ from repro.train import train_loop
 
 
 def _mesh3():
-    return jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    return mesh_lib.make_mesh((1, 1, 1), ("pod", "data", "model"))
 
 
 def _rules(cfg, mesh, kind="train"):

@@ -164,7 +164,7 @@ def test_walker_xla_costanalysis_disagrees():
     x = jax.ShapeDtypeStruct((128, 128), jnp.bfloat16)
     ws = jax.ShapeDtypeStruct((8, 128, 128), jnp.bfloat16)
     c = jax.jit(scanned).lower(x, ws).compile()
-    xla_flops = hlo_costs.xla_cost_analysis(c)["flops"]
+    xla_flops = c.cost_analysis()["flops"]
     walker_flops = hlo_costs.module_costs(c.as_text())["flops"]
     # XLA reports ~1 loop body (plus small elementwise terms); the walker
     # counts all 8 trips of the matmul.
