@@ -210,16 +210,18 @@ def precision_switch(state: Dict[str, Any], params: PyTree,
                      qcfg: QuantConfig) -> Dict[str, Any]:
     """Alg. 2: AdaptStrategy, then per tensor Adapt{Lookback,Resolution} +
     PushDown + PushUp where the window is full."""
-    lb_avg = _avg_lookback(state)
-    loss_avg, loss_now = _loss_stats(state, lb_avg)
-    strategy = pushup.adapt_strategy(state["strategy"], loss_avg, loss_now)
-
-    flat = dict(
-        (path_str(p), w) for p, w in jax.tree_util.tree_flatten_with_path(params)[0])
-    tensors = {
-        path: _switch_tensor(ts, flat[path].astype(jnp.float32), strategy, qcfg)
-        for path, ts in state["tensors"].items()
-    }
+    with jax.named_scope("adapt.switch"):
+        lb_avg = _avg_lookback(state)
+        loss_avg, loss_now = _loss_stats(state, lb_avg)
+        strategy = pushup.adapt_strategy(state["strategy"], loss_avg,
+                                         loss_now)
+        flat = dict((path_str(p), w) for p, w in
+                    jax.tree_util.tree_flatten_with_path(params)[0])
+        tensors = {
+            path: _switch_tensor(ts, flat[path].astype(jnp.float32),
+                                 strategy, qcfg)
+            for path, ts in state["tensors"].items()
+        }
     return {**state, "tensors": tensors, "strategy": strategy}
 
 

@@ -3,6 +3,8 @@
     PYTHONPATH=src python -m repro.launch.train --arch tiny --steps 50
     PYTHONPATH=src python -m repro.launch.train --arch granite-8b \
         --shape train_4k --override quant.mode=simulate --dry-steps 3
+    PYTHONPATH=src python -m repro.launch.train --arch tiny --steps 30 \
+        --trace-dir /tmp/prof --trace-steps 20:25
 
 On a real TPU pod this process runs per host (jax.distributed.initialize is
 called when the coordinator env vars are present); in this container it runs
@@ -36,7 +38,20 @@ def main(argv=None):
     ap.add_argument("--metrics-dir", default="",
                     help="write JSONL step/switch telemetry here")
     ap.add_argument("--override", action="append", default=[])
+    ap.add_argument("--trace-dir", default="",
+                    help="write a jax.profiler trace (.xplane.pb) here")
+    ap.add_argument("--trace-steps", default="", metavar="A:B",
+                    help="with --trace-dir: trace steps A to B-1")
     args = ap.parse_args(argv)
+    trace = None
+    if args.trace_dir or args.trace_steps:
+        try:
+            first, stop = (int(v) for v in args.trace_steps.split(":"))
+        except ValueError:
+            ap.error("--trace-steps takes A:B, two step numbers")
+        if not args.trace_dir or stop <= first:
+            ap.error("--trace-dir and --trace-steps A:B (A < B) go together")
+        trace = (args.trace_dir, first, stop)
     use_compile_cache()
 
     if "COORDINATOR_ADDRESS" in os.environ:   # multi-host entry
@@ -83,7 +98,7 @@ def main(argv=None):
             cfg, steps=args.steps, state=state, checkpoint_mgr=mgr,
             watchdog=watchdog, telemetry=telemetry,
             metrics_logger=metrics_logger, preemption_guard=guard,
-            heartbeat=Heartbeat())
+            heartbeat=Heartbeat(), trace=trace)
     if metrics_logger is not None:
         metrics_logger.log_event("finished", steps=int(state["step"]))
         metrics_logger.close()

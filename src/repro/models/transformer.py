@@ -248,17 +248,20 @@ def forward(params: Dict[str, Any], cfg: ModelConfig, *,
     elif remat == "selective":
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    x, _ = jax.lax.scan(body, x, xs)
+    with jax.named_scope("adapt.layers"):
+        x, _ = jax.lax.scan(body, x, xs)
 
-    x = common.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params.get("head")
-    if head is None:
-        logits = common.dense(x, params["embed"].T)
-    else:
-        logits = common.dense(x, head, out_logical="vocab",
-                              use_pallas=use_pallas)
-    logits = common.softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
-    return sharding.shard(logits, "batch", "seq", "vocab")
+    with jax.named_scope("adapt.head"):
+        x = common.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = params.get("head")
+        if head is None:
+            logits = common.dense(x, params["embed"].T)
+        else:
+            logits = common.dense(x, head, out_logical="vocab",
+                                  use_pallas=use_pallas)
+        logits = common.softcap(logits.astype(jnp.float32),
+                                cfg.final_logit_softcap)
+        return sharding.shard(logits, "batch", "seq", "vocab")
 
 
 # ---------------------------------------------------------------------------

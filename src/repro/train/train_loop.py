@@ -70,8 +70,10 @@ def _task_loss(cfg: Config, qparams, stats, batch, act_wl=None,
     m = cfg.model
     if m.family == "cnn":
         _, fwd = cnn.MODELS[m.name.replace("-smoke", "")]
-        logits, new_stats = fwd(qparams, stats, batch["images"], train)
-        loss = cnn.ce_loss(logits, batch["labels"])
+        with jax.named_scope("adapt.forward"):
+            logits, new_stats = fwd(qparams, stats, batch["images"], train)
+        with jax.named_scope("adapt.loss"):
+            loss = cnn.ce_loss(logits, batch["labels"])
         return loss, {"stats": new_stats,
                       "acc": cnn.accuracy(logits, batch["labels"])}
     kwargs = {}
@@ -96,10 +98,13 @@ def _task_loss(cfg: Config, qparams, stats, batch, act_wl=None,
     # XLA path in attend_full), the CNN family's conv forward, and
     # non-dense quantized leaves (embed/conv/MoE-expert weights —
     # dequantized at their use site; fixed_point.DENSE_PARAM_NAMES).
-    logits = transformer.forward(qparams, m, act_wl=act_wl,
-                                 use_pallas=cfg.quant.use_pallas,
-                                 remat=cfg.train.remat, **kwargs)
-    return transformer.lm_loss(logits, targets, shift=shift), {"stats": stats}
+    with jax.named_scope("adapt.forward"):
+        logits = transformer.forward(qparams, m, act_wl=act_wl,
+                                     use_pallas=cfg.quant.use_pallas,
+                                     remat=cfg.train.remat, **kwargs)
+    with jax.named_scope("adapt.loss"):
+        loss = transformer.lm_loss(logits, targets, shift=shift)
+    return loss, {"stats": stats}
 
 
 # ---------------------------------------------------------------------------
@@ -136,27 +141,28 @@ def make_train_step(cfg: Config, qparam_shardings=None,
         act_wl = None
         packed = False
         if qcfg.mode != "off":
-            qkey = step_key if qcfg.stochastic_rounding else None
-            if qcfg.container_dtype == "int8_packed" and \
-                    cfg.model.family != "cnn":
-                # native-int8 wire format: weights cross the mesh as int8,
-                # dequantized inside the scan body after the per-layer
-                # gather (§Perf / DESIGN §3)
-                packed = True
-                qparams = controller.quantize_params_packed(
-                    params, adapt, qcfg, qkey, shardings=qparam_shardings)
-            else:
-                container = {"bfloat16": jnp.bfloat16,
-                             "int8": jnp.int8}.get(qcfg.container_dtype,
-                                                   jnp.float32)
-                qparams = controller.quantize_params(
-                    params, adapt, qcfg, qkey, dtype=container,
-                    shardings=qparam_shardings)
-                if qparam_shardings is not None:
-                    qparams = jax.lax.with_sharding_constraint(
-                        qparams, qparam_shardings)
-            if cfg.model.family != "cnn" and qcfg.quantize_activations:
-                act_wl = transformer.act_wl_from_state(adapt)
+            with jax.named_scope("adapt.quantize"):
+                qkey = step_key if qcfg.stochastic_rounding else None
+                if qcfg.container_dtype == "int8_packed" and \
+                        cfg.model.family != "cnn":
+                    # native-int8 wire format: weights cross the mesh as int8,
+                    # dequantized inside the scan body after the per-layer
+                    # gather (§Perf / DESIGN §3)
+                    packed = True
+                    qparams = controller.quantize_params_packed(
+                        params, adapt, qcfg, qkey, shardings=qparam_shardings)
+                else:
+                    container = {"bfloat16": jnp.bfloat16,
+                                 "int8": jnp.int8}.get(qcfg.container_dtype,
+                                                       jnp.float32)
+                    qparams = controller.quantize_params(
+                        params, adapt, qcfg, qkey, dtype=container,
+                        shardings=qparam_shardings)
+                    if qparam_shardings is not None:
+                        qparams = jax.lax.with_sharding_constraint(
+                            qparams, qparam_shardings)
+                if cfg.model.family != "cnn" and qcfg.quantize_activations:
+                    act_wl = transformer.act_wl_from_state(adapt)
         else:
             qparams = params
 
@@ -166,10 +172,11 @@ def make_train_step(cfg: Config, qparam_shardings=None,
                 # reg terms on an eagerly-unpacked view: elementwise +
                 # scalar reductions only, so it stays fully sharded (no
                 # gathers); its cotangents add onto the same wrefs.
-                reg_tree = fxp.unpack_tree(qp) if packed else qp
-                full = sparsity.adapt_loss(
-                    task, reg_tree, adapt, alpha=ocfg.l1, beta=ocfg.l2,
-                    penalty_coef=ocfg.penalty_coef, max_wl=qcfg.max_wl)
+                with jax.named_scope("adapt.regularize"):
+                    reg_tree = fxp.unpack_tree(qp) if packed else qp
+                    full = sparsity.adapt_loss(
+                        task, reg_tree, adapt, alpha=ocfg.l1, beta=ocfg.l2,
+                        penalty_coef=ocfg.penalty_coef, max_wl=qcfg.max_wl)
             else:
                 full = task
             return full, (task, aux)
@@ -229,18 +236,23 @@ def make_train_step(cfg: Config, qparam_shardings=None,
         else:
             loss, task, aux, grads = compute_grads(qparams, batch)
         if dp_axes:       # equal shards: the mean of means is the global mean
-            loss, task, grads = jax.lax.pmean((loss, task, grads), dp_axes)
+            with jax.named_scope("adapt.grad_sync"):
+                loss, task, grads = jax.lax.pmean((loss, task, grads),
+                                                  dp_axes)
 
         if qcfg.mode != "off":
-            adapt = controller.accumulate(adapt, grads, task)
-            grads = opt_lib.normalize_grads(grads, set(adapt["tensors"]))
-        grads = opt_lib.clip_by_global_norm(grads, ocfg.grad_clip)
-
-        opt = opt_lib.rop_update(state["opt"], task, ocfg)
-        params, opt = opt_lib.apply_updates(params, grads, opt, ocfg)
+            with jax.named_scope("adapt.accumulate"):
+                adapt = controller.accumulate(adapt, grads, task)
+        with jax.named_scope("adapt.update"):
+            if qcfg.mode != "off":
+                grads = opt_lib.normalize_grads(grads, set(adapt["tensors"]))
+            grads = opt_lib.clip_by_global_norm(grads, ocfg.grad_clip)
+            opt = opt_lib.rop_update(state["opt"], task, ocfg)
+            params, opt = opt_lib.apply_updates(params, grads, opt, ocfg)
+            grad_norm = _global_norm(grads)
 
         metrics = {"loss": task, "full_loss": loss, "lr": opt["lr"],
-                   "grad_norm": _global_norm(grads)}
+                   "grad_norm": grad_norm}
         if "acc" in aux:
             metrics["acc"] = aux["acc"]
         new_state = {
@@ -352,10 +364,25 @@ def train(cfg: Config, *, steps: Optional[int] = None,
           telemetry: Optional[list] = None,
           metrics_logger=None, preemption_guard=None,
           heartbeat=None, mesh=None,
-          step_fn: Optional[Callable] = None) -> Tuple[Dict[str, Any], list]:
+          step_fn: Optional[Callable] = None,
+          trace: Optional[Tuple[str, int, int]] = None
+          ) -> Tuple[Dict[str, Any], list]:
     """Run the loop; returns (state, history). ``telemetry`` (if a list)
     collects per-switch controller snapshots for the paper's perf model;
     ``metrics_logger`` (train.metrics.MetricsLogger) streams JSONL.
+
+    ``dt`` (in ``history``, ``metrics_logger.log_step`` and the
+    ``watchdog``'s samples) is the wall time per step between two host
+    syncs: the loss reads every ``log_every`` steps. It covers everything
+    between the two reads (batches, steps, switches, checkpoint saves) over
+    the steps in between; the first also covers the step's compile.
+
+    ``trace``: (directory, first, stop) profiles steps ``first`` to
+    ``stop - 1`` into ``directory`` with ``jax.profiler``. Each step is a
+    ``StepTraceAnnotation`` ("train") holding the host spans
+    ``train.batch``, ``train.step``, ``train.switch``, ``train.log_read``
+    and ``train.checkpoint``, on the device trace's clock; the device ops
+    carry the step's ``adapt.*`` scopes.
 
     ``mesh``: train data parallel over it (``data_parallel_step``); state
     and batches are placed on it. ``step_fn``: an already-compiled step
@@ -385,43 +412,71 @@ def train(cfg: Config, *, steps: Optional[int] = None,
         switch_fn = None
     interval = cfg.train.adapt_interval or cfg.quant.lb_lwr
 
+    tp = jax.profiler
+    trace_dir, trace_first, trace_stop = trace or (None, -1, -1)
+    tracing = False
     history = []
     start_step = int(state["step"])
-    for i in range(start_step, start_step + steps):
-        t0 = time.perf_counter()
-        batch = make_batch(cfg, i)
-        if batch_sh is not None:
-            batch = jax.device_put(batch, batch_sh)
-        state, metrics = step_fn(state, batch)
-        if switch_fn is not None and (i + 1) % interval == 0:
-            state = switch_fn(state)
-            if telemetry is not None or metrics_logger is not None:
-                snap = controller.snapshot(state["adapt"])
-                if telemetry is not None:
-                    telemetry.append(snap)
-                if metrics_logger is not None:
-                    metrics_logger.log_switch(i + 1, snap)
-        dt = time.perf_counter() - t0
-        if watchdog is not None:
-            watchdog.observe(i, dt)
-        if (i + 1) % max(cfg.train.log_every, 1) == 0:
-            m = {k: float(v) for k, v in metrics.items()}
-            history.append({"step": i + 1, **m, "dt": dt})
-            if metrics_logger is not None:
-                metrics_logger.log_step(i + 1, m, dt=dt)
-            log(f"step {i + 1:5d} loss={m['loss']:.4f} lr={m['lr']:.4g} "
-                + (f"acc={m['acc']:.3f} " if "acc" in m else "")
-                + f"({dt * 1e3:.0f} ms)")
-        if checkpoint_mgr is not None and cfg.train.checkpoint_every and \
-                (i + 1) % cfg.train.checkpoint_every == 0:
-            checkpoint_mgr.save(state, step=i + 1)
-        if heartbeat is not None:
-            heartbeat.beat(i + 1, extra=f"loss={float(metrics['loss']):.4f}")
-        if preemption_guard is not None and preemption_guard.requested:
-            log(f"[preempt] SIGTERM at step {i + 1}: saving final "
-                "checkpoint and exiting")
-            if checkpoint_mgr is not None:
-                checkpoint_mgr.save(state, step=i + 1)
-                checkpoint_mgr.wait()
-            break
+    t_sync, synced = time.perf_counter(), start_step
+    try:
+        for i in range(start_step, start_step + steps):
+            if i == trace_first:
+                tp.start_trace(trace_dir)
+                tracing = True
+            with tp.StepTraceAnnotation("train", step_num=i):
+                with tp.TraceAnnotation("train.batch"):
+                    batch = make_batch(cfg, i)
+                    if batch_sh is not None:
+                        batch = jax.device_put(batch, batch_sh)
+                with tp.TraceAnnotation("train.step"):
+                    state, metrics = step_fn(state, batch)
+                if switch_fn is not None and (i + 1) % interval == 0:
+                    with tp.TraceAnnotation("train.switch"):
+                        state = switch_fn(state)
+                        if telemetry is not None or \
+                                metrics_logger is not None:
+                            snap = controller.snapshot(state["adapt"])
+                            if telemetry is not None:
+                                telemetry.append(snap)
+                            if metrics_logger is not None:
+                                metrics_logger.log_switch(i + 1, snap)
+                if (i + 1) % max(cfg.train.log_every, 1) == 0:
+                    with tp.TraceAnnotation("train.log_read"):
+                        m = {k: float(v) for k, v in metrics.items()}
+                    now = time.perf_counter()
+                    dt = (now - t_sync) / (i + 1 - synced)
+                    t_sync, synced = now, i + 1
+                    if watchdog is not None:
+                        watchdog.observe(i, dt)
+                    history.append({"step": i + 1, **m, "dt": dt})
+                    if metrics_logger is not None:
+                        metrics_logger.log_step(i + 1, m, dt=dt)
+                    log(f"step {i + 1:5d} loss={m['loss']:.4f} "
+                        f"lr={m['lr']:.4g} "
+                        + (f"acc={m['acc']:.3f} " if "acc" in m else "")
+                        + f"({dt * 1e3:.0f} ms)")
+                if checkpoint_mgr is not None and \
+                        cfg.train.checkpoint_every and \
+                        (i + 1) % cfg.train.checkpoint_every == 0:
+                    with tp.TraceAnnotation("train.checkpoint"):
+                        checkpoint_mgr.save(state, step=i + 1)
+                if heartbeat is not None:
+                    heartbeat.beat(i + 1,
+                                   extra=f"loss={float(metrics['loss']):.4f}")
+                if preemption_guard is not None and \
+                        preemption_guard.requested:
+                    log(f"[preempt] SIGTERM at step {i + 1}: saving final "
+                        "checkpoint and exiting")
+                    if checkpoint_mgr is not None:
+                        with tp.TraceAnnotation("train.checkpoint"):
+                            checkpoint_mgr.save(state, step=i + 1)
+                            checkpoint_mgr.wait()
+                    break
+            if tracing and i + 1 == trace_stop:
+                jax.block_until_ready(state)
+                tp.stop_trace()
+                tracing = False
+    finally:
+        if tracing:
+            tp.stop_trace()
     return state, history

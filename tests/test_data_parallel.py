@@ -105,6 +105,22 @@ def test_four_way_matches_one_device(extra):
     assert all(bool(jnp.isfinite(h["loss"])) for h in dp)
 
 
+@multi
+def test_gradient_exchange_carries_grad_sync_scope():
+    """Every all-reduce of the compiled step is the gradient exchange, and
+    carries the ``adapt.grad_sync`` scope a trace reads it by."""
+    cfg = _cfg()
+    mesh = mesh_lib.make_mesh((4, 1), ("data", "model"), jax.devices()[:4])
+    state = jax.eval_shape(lambda: train_loop.init_state(cfg))
+    batch = jax.eval_shape(lambda: train_loop.make_batch(cfg, 0))
+    step, _, _, _ = train_loop.data_parallel_step(cfg, mesh, state, batch)
+    text = step.lower(state, batch).compile().as_text()
+    reduces = [l for l in text.splitlines()
+               if " all-reduce(" in l or " all-reduce-start(" in l]
+    assert reduces
+    assert all("adapt.grad_sync" in l for l in reduces), reduces[0][-300:]
+
+
 @pytest.mark.skipif(
     N_DEV >= 4 or os.environ.get("GITHUB_ACTIONS") == "true",
     reason="already running multi-device, or CI (the multidevice-4 matrix "

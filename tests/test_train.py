@@ -117,6 +117,49 @@ def test_watchdog_flags_stragglers():
         wd2.observe(3, 1.0)
 
 
+def test_dt_is_wall_time_per_step_between_loss_reads():
+    """``dt`` spans two host syncs (the loss reads every ``log_every``
+    steps), so the intervals add up to the loop's wall time."""
+    import time
+    cfg = _tiny_cfg(log_every=2)
+    state = train_loop.init_state(cfg)
+    wd = StepWatchdog()
+    t0 = time.perf_counter()
+    _, hist = train_loop.train(cfg, steps=6, state=state, watchdog=wd,
+                               log=lambda s: None)
+    wall = time.perf_counter() - t0
+    assert [h["step"] for h in hist] == [2, 4, 6]
+    assert wd.times == [h["dt"] for h in hist]
+    covered = sum(2 * h["dt"] for h in hist)
+    assert 0.5 * wall < covered <= wall
+
+
+def test_trace_steps_write_host_spans(tmp_path):
+    """``train(trace=(dir, 1, 3))`` profiles steps 1 and 2: the per-step
+    annotation and the host spans land in the written ``.xplane.pb``."""
+    import glob
+    from jax.profiler import ProfileData
+    cfg = _tiny_cfg(adapt_interval=2, log_every=1, checkpoint_every=2)
+    mgr = CheckpointManager(str(tmp_path / "ck"), keep=1, async_save=False)
+    train_loop.train(cfg, steps=4, checkpoint_mgr=mgr, log=lambda s: None,
+                     trace=(str(tmp_path / "prof"), 1, 3))
+    path, = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == "train" or e.name.startswith("train."):
+                    spans.setdefault(e.name, []).append(
+                        dict(e.stats).get("step_num"))
+    assert set(spans) == {"train", "train.batch", "train.step",
+                          "train.switch", "train.log_read",
+                          "train.checkpoint"}
+    assert sorted(spans["train"]) == [1, 2]
+    assert len(spans["train.step"]) == 2
+    assert len(spans["train.switch"]) == len(spans["train.checkpoint"]) == 1
+
+
 def test_retry_and_preemption_guard():
     calls = []
 
