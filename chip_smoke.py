@@ -139,19 +139,22 @@ def kernel_phase(label):
     check(same and moved > 0.1, "hw_prng stream not deterministic per seed")
 
     # partial boundary blocks in every dim: 1000 = 3·256 + 232 = 512 + 488
+    # (the shape rule would take 1000 whole, so the blocks are requested)
     k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(3), 4)
     xm = jax.random.normal(k1, (1000, 1000))
     wq = jax.random.randint(k2, (1000, 1000), -128, 128).astype(jnp.int8)
     sc = jnp.float32(2.0 ** -7)
     dy = jax.random.normal(k3, (1000, 1000))
+    blocks = dict(bm=256, bn=256, bk=512)
 
     def mm(x):
-        return fm.fxp_dense_vjp(x, wq, sc, jnp.zeros(wq.shape, jnp.float32))
+        return fm.fxp_dense_vjp(x, wq, sc, jnp.zeros(wq.shape, jnp.float32),
+                                **blocks)
 
     y, vjp = jax.vjp(mm, xm)
     dx, = vjp(dy)
     dw = jax.grad(lambda w: jnp.sum(fm.fxp_dense_vjp(
-        xm, wq, sc, w) * dy))(jnp.zeros(wq.shape, jnp.float32))
+        xm, wq, sc, w, **blocks) * dy))(jnp.zeros(wq.shape, jnp.float32))
     q = jax.random.normal(k4, (1, 1000, 3, 64))
     kv = jax.random.normal(k1, (1, 1000, 1, 64))
     o, avjp = jax.vjp(lambda a, b, c: fa.flash_attention_vjp(a, b, c), q, kv,

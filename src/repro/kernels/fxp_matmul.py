@@ -9,10 +9,14 @@ into VMEM (4× less HBM traffic than f32, 2× less than bf16) and dequantizes
 in-register on the way into the MXU.
 
 Block scheme: grid (⌈M/bm⌉, ⌈N/bn⌉, ⌈K/bk⌉), K innermost so the f32
-accumulator tile lives in a VMEM scratch across the K loop; MXU-aligned
-128-multiples preferred but NOT required — partial boundary blocks are
-tail-masked in-kernel (``_mask_tail``: Pallas pads them with garbage/NaN),
-so any ⟨M,K,N⟩ runs with the requested block clamp and bounded VMEM.
+accumulator tile lives in a VMEM scratch across the K loop. A block not
+given is the shape rule's (``_dense_blocks``: from the kernel kind, the
+three extents and the operand dtypes); MXU-aligned 128-multiples preferred
+but NOT required — partial boundary blocks are tail-masked in-kernel
+(``_mask_tail``: Pallas pads them with garbage/NaN), so any ⟨M,K,N⟩ runs
+with the block clamped to its dim and bounded VMEM. The MXU takes the
+operands in their stored dtype (the int8 words cast to the activation's),
+accumulating in f32.
 
 A full-integer variant (``int8_matmul``) takes int8 activations too and
 accumulates in int32 — the v5e MXU's 2× int8 throughput path; used for
@@ -60,6 +64,130 @@ def _clamp_block(b: int, d: int) -> int:
     return min(b, d)
 
 
+# ---------------------------------------------------------------------------
+# Block rule: tiles from the product's shape.
+#
+# Each dense kernel computes one product C[P, Q] = A[P, R] · B[R, Q] on a
+# grid whose innermost axis walks the contracted R, with the (p, q) output
+# tile accumulated in f32 VMEM. A grid step reads a (p, r) A tile and an
+# (r, q) B tile from HBM for 2·p·q·r FLOPs, so its arithmetic intensity is
+# 2·p·q / (p·|A| + q·|B|) FLOP/byte (|·| = bytes per element): the output
+# tile alone sets it, while r sets how much work a step carries against
+# its fixed cost. The rule starts from 1024 on every side (the best of the
+# blocks swept on a v5e at both benchmark cells' shapes), grows the side
+# that carries the larger share of the bytes until the step clears v5e's
+# ridge (197 TFLOP/s bf16 or 393 TOP/s int8 over 819 GB/s of HBM: 240 /
+# 480 FLOP/byte) where the product's extents allow it at all, then shrinks
+# r, and after it the wider output side, until the reckoned VMEM fits the
+# budget. A dim no larger than its block is one whole, unmasked block; a
+# larger one is cut into equal lane multiples where it can be
+# (``_split_dim``). Small M (serving decode) is therefore one whole row
+# block, and the wide word tiles stream the weights.
+
+# kind → which of (M, K, N) is P, Q and R.
+_DENSE_KINDS = {
+    "fwd": ("M", "N", "K"),     # fxp_matmul: x @ words
+    "int8": ("M", "N", "K"),    # int8_matmul: xq @ wq
+    "qfwd": ("M", "N", "K"),    # fxp_qmatmul: x @ Q(master)
+    "dx": ("M", "K", "N"),      # matmul_dx: dy @ wordsᵀ
+    "qdx": ("M", "K", "N"),     # matmul_qdx: dy @ Q(master)ᵀ
+    "dw": ("K", "N", "M"),      # matmul_dw: xᵀ @ dy
+}
+_DENSE_CAP = 1024
+_RIDGE_BF16, _RIDGE_INT8 = 240, 480     # FLOP/byte on v5e
+_LANE = 128
+_VMEM_BUDGET = 48 * 2**20               # reckoned bytes a rule block may take
+_VMEM_SCOPED = 16 * 2**20               # Mosaic's default scoped VMEM (v5e)
+_QUANT_TEMPS = 8                        # f32 (r, q) tiles of _quantize_w_tile
+
+
+def _split_dim(d: int, cap: int) -> int:
+    """Block for a dim of extent ``d`` under ``cap`` (a lane multiple): the
+    whole dim when ``d <= cap``; else the fewest equal blocks that are lane
+    multiples, where up to twice the fewest blocks give such a cut; else
+    the fewest blocks, rounded up to a lane multiple (one tail block)."""
+    if d <= cap:
+        return d
+    n = -(-d // cap)
+    for m in range(n, 2 * n + 1):
+        if d % (m * _LANE) == 0:
+            return d // m
+    return -(-(-(-d // n)) // _LANE) * _LANE
+
+
+def _dense_vmem(kind: str, p: int, q: int, r: int, a_bytes: int,
+                b_bytes: int, out_bytes: int) -> int:
+    """Reckoned VMEM of one grid step: the double-buffered A, B and output
+    tiles, the 4-byte accumulator and the dot's 4-byte result, and the
+    kind's in-register temporaries (the words cast to A's dtype; dw's A
+    tile transposed for the MXU; the prologue's f32 A tile and its
+    quantize temporaries)."""
+    need = 2 * (p * r * a_bytes + r * q * b_bytes) + 2 * p * q * out_bytes
+    need += 2 * p * q * 4
+    if kind in ("fwd", "dx"):
+        need += r * q * a_bytes
+    elif kind == "dw":
+        need += p * r * a_bytes
+    elif kind in ("qfwd", "qdx"):
+        need += p * r * 4 + _QUANT_TEMPS * r * q * 4
+    return need
+
+
+def _dense_blocks(kind: str, M: int, K: int, N: int, a_dtype, b_dtype,
+                  out_dtype) -> dict:
+    """The rule's blocks for ``kind``'s product over (M, K, N), keyed by
+    dim name ("M", "K", "N"). A is the operand that shares the output's
+    rows (x, dy, or x for dw), B the other; the result depends on the
+    kind, the three extents and the three dtypes only."""
+    P, Q, R = (dict(M=M, K=K, N=N)[d] for d in _DENSE_KINDS[kind])
+    a, b, o = (jnp.dtype(t).itemsize for t in (a_dtype, b_dtype, out_dtype))
+    p, q, r = (_split_dim(d, _DENSE_CAP) for d in (P, Q, R))
+    ridge = _RIDGE_INT8 if kind == "int8" else _RIDGE_BF16
+    reach = 2 * P * Q >= ridge * (P * a + Q * b)    # else no tile clears it
+    while reach and 2 * p * q < ridge * (p * a + q * b):
+        # p amortizes B's re-reads (q·b of a step's bytes), q A's (p·a).
+        if p < P and (b * q >= a * p or q == Q):
+            p = _split_dim(P, 2 * p)
+        elif q < Q:
+            q = _split_dim(Q, 2 * q)
+        else:
+            break
+    half = lambda blk: max(_LANE, blk // 2 // _LANE * _LANE)
+    while _dense_vmem(kind, p, q, r, a, b, o) > _VMEM_BUDGET:
+        if r > 4 * _LANE:
+            r = _split_dim(R, half(r))
+        elif q >= p and q > _LANE:
+            q = _split_dim(Q, half(q))
+        elif p > _LANE:
+            p = _split_dim(P, half(p))
+        else:
+            break
+    return dict(zip(_DENSE_KINDS[kind], (p, q, r)))
+
+
+def _pick_blocks(kind: str, M: int, K: int, N: int, bm, bn, bk, a_dtype,
+                 b_dtype, out_dtype):
+    """(bm, bn, bk): each requested block, else the rule's, clamped to its
+    dim; and the ``CompilerParams`` for them. Past Mosaic's default scoped
+    VMEM they ask for their reckoning and a quarter more (Mosaic used 80–
+    100% of the reckoning at both cells' shapes), and no more: what a
+    kernel reserves, XLA's own ops around it cannot use."""
+    rule = _dense_blocks(kind, M, K, N, a_dtype, b_dtype, out_dtype)
+    bm, bn, bk = (_clamp_block(rule[n] if b is None else b, d)
+                  for b, n, d in ((bm, "M", M), (bn, "N", N), (bk, "K", K)))
+    blk = dict(M=bm, N=bn, K=bk)
+    p, q, r = (blk[d] for d in _DENSE_KINDS[kind])
+    need = _dense_vmem(kind, p, q, r, jnp.dtype(a_dtype).itemsize,
+                       jnp.dtype(b_dtype).itemsize,
+                       jnp.dtype(out_dtype).itemsize)
+    limit = None if need <= _VMEM_SCOPED else min(need + need // 4,
+                                                   100 * 2**20)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=limit)
+    return bm, bn, bk, params
+
+
 def _mask_tail(x: Array, axis: int, pid, dim: int) -> Array:
     """Zero the garbage-padding tail of a boundary block along ``axis``.
 
@@ -70,6 +198,12 @@ def _mask_tail(x: Array, axis: int, pid, dim: int) -> Array:
     b = x.shape[axis]
     if dim % b == 0:
         return x
+    if x.dtype.itemsize < 4 and x.shape[0] < 8:
+        # Mosaic cannot broadcast the mask over the sublanes of a packed
+        # tile this short (decode rows): select in 32 bits.
+        wide = jnp.float32 if jnp.issubdtype(x.dtype, jnp.floating) \
+            else jnp.int32
+        return _mask_tail(x.astype(wide), axis, pid, dim).astype(x.dtype)
     idx = b * pid + jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
     return jnp.where(idx < dim, x, jnp.zeros_like(x))
 
@@ -91,8 +225,10 @@ def _fxp_matmul_kernel(x_ref, w_ref, scale_ref, o_ref, acc_ref, *, nk: int,
 
     # K is contracted: garbage in EITHER operand's K tail would poison
     # every output element (0·NaN = NaN), so both tails go to exact zero.
-    x = _mask_tail(x_ref[...].astype(jnp.float32), 1, ik, K)
-    w = _mask_tail(w_ref[...].astype(jnp.float32), 0, ik, K)
+    # The words (|q| ≤ 128) are exact in the activation's dtype, so the MXU
+    # takes both operands as stored.
+    x = _mask_tail(x_ref[...], 1, ik, K)
+    w = _mask_tail(w_ref[...].astype(x.dtype), 0, ik, K)
     acc_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -108,8 +244,8 @@ def _fxp_matmul_kernel(x_ref, w_ref, scale_ref, o_ref, acc_ref, *, nk: int,
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
                                              "out_dtype"))
-def fxp_matmul(x: Array, wq: Array, scale: Array, *, bm: int = 256,
-               bn: int = 256, bk: int = 512, out_dtype=None,
+def fxp_matmul(x: Array, wq: Array, scale: Array, *, bm: int | None = None,
+               bn: int | None = None, bk: int | None = None, out_dtype=None,
                interpret: bool = False) -> Array:
     """y = x @ (wq * scale).  x: (M,K) float; wq: (K,N) int8; scale: () f32.
 
@@ -120,7 +256,8 @@ def fxp_matmul(x: Array, wq: Array, scale: Array, *, bm: int = 256,
     K2, N = wq.shape
     assert K == K2, (x.shape, wq.shape)
     out_dtype = out_dtype or x.dtype
-    bm, bn, bk = _clamp_block(bm, M), _clamp_block(bn, N), _clamp_block(bk, K)
+    bm, bn, bk, params = _pick_blocks("fwd", M, K, N, bm, bn, bk, x.dtype,
+                                      wq.dtype, out_dtype)
     grid = (pl.cdiv(M, bm), pl.cdiv(N, bn), pl.cdiv(K, bk))
     kernel = functools.partial(_fxp_matmul_kernel, nk=grid[2],
                                dims=(M, K, N))
@@ -136,8 +273,7 @@ def fxp_matmul(x: Array, wq: Array, scale: Array, *, bm: int = 256,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=params,
     )(x, wq, scale.reshape(1, 1).astype(jnp.float32))
 
 
@@ -166,14 +302,16 @@ def _int8_matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nk: int,
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def int8_matmul(xq: Array, wq: Array, sx: Array, sw: Array, *, bm: int = 256,
-                bn: int = 256, bk: int = 512, interpret: bool = False) -> Array:
+def int8_matmul(xq: Array, wq: Array, sx: Array, sw: Array, *,
+                bm: int | None = None, bn: int | None = None,
+                bk: int | None = None, interpret: bool = False) -> Array:
     """W8A8 path: (xq @ wq) * (sx*sw); int32 MXU accumulation, f32 out.
     Accepts any ⟨M,K,N⟩ — partial boundary blocks are tail-masked."""
     M, K = xq.shape
     K2, N = wq.shape
     assert K == K2, (xq.shape, wq.shape)
-    bm, bn, bk = _clamp_block(bm, M), _clamp_block(bn, N), _clamp_block(bk, K)
+    bm, bn, bk, params = _pick_blocks("int8", M, K, N, bm, bn, bk, xq.dtype,
+                                      wq.dtype, jnp.float32)
     grid = (pl.cdiv(M, bm), pl.cdiv(N, bn), pl.cdiv(K, bk))
     kernel = functools.partial(_int8_matmul_kernel, nk=grid[2],
                                dims=(M, K, N))
@@ -190,8 +328,7 @@ def int8_matmul(xq: Array, wq: Array, sx: Array, sw: Array, *, bm: int = 256,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=params,
     )(xq, wq, s)
 
 
@@ -212,8 +349,8 @@ def _matmul_dx_kernel(dy_ref, w_ref, scale_ref, dx_ref, acc_ref, *, nn: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # N is the contracted dim here — zero both N tails before the MXU.
-    dy = _mask_tail(dy_ref[...].astype(jnp.float32), 1, n, N)
-    w = _mask_tail(w_ref[...].astype(jnp.float32), 1, n, N)
+    dy = _mask_tail(dy_ref[...], 1, n, N)
+    w = _mask_tail(w_ref[...].astype(dy.dtype), 1, n, N)
     acc_ref[...] += jax.lax.dot_general(
         dy, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -226,15 +363,16 @@ def _matmul_dx_kernel(dy_ref, w_ref, scale_ref, dx_ref, acc_ref, *, nn: int,
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
                                              "out_dtype"))
-def matmul_dx(dy: Array, wq: Array, scale: Array, *, bm: int = 256,
-              bn: int = 256, bk: int = 512, out_dtype=None,
+def matmul_dx(dy: Array, wq: Array, scale: Array, *, bm: int | None = None,
+              bn: int | None = None, bk: int | None = None, out_dtype=None,
               interpret: bool = False) -> Array:
     """dx = dy @ (wq * scale)ᵀ.  dy: (M,N); wq: (K,N) int8; out (M,K)."""
     M, N = dy.shape
     K, N2 = wq.shape
     assert N == N2, (dy.shape, wq.shape)
     out_dtype = out_dtype or dy.dtype
-    bm, bk, bn = _clamp_block(bm, M), _clamp_block(bk, K), _clamp_block(bn, N)
+    bm, bn, bk, params = _pick_blocks("dx", M, K, N, bm, bn, bk, dy.dtype,
+                                      wq.dtype, out_dtype)
     grid = (pl.cdiv(M, bm), pl.cdiv(K, bk), pl.cdiv(N, bn))
     kernel = functools.partial(_matmul_dx_kernel, nn=grid[2],
                                dims=(M, K, N))
@@ -250,8 +388,7 @@ def matmul_dx(dy: Array, wq: Array, scale: Array, *, bm: int = 256,
         out_shape=jax.ShapeDtypeStruct((M, K), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
         interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=params,
     )(dy, wq, scale.reshape(1, 1).astype(jnp.float32))
 
 
@@ -265,8 +402,10 @@ def _matmul_dw_kernel(x_ref, dy_ref, dw_ref, acc_ref, *, nm: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # M is the contracted dim here — zero both M tails before the MXU.
-    x = _mask_tail(x_ref[...].astype(jnp.float32), 0, m, M)
-    dy = _mask_tail(dy_ref[...].astype(jnp.float32), 0, m, M)
+    # Operands go in as stored (one common dtype where they differ).
+    ct = jnp.promote_types(x_ref.dtype, dy_ref.dtype)
+    x = _mask_tail(x_ref[...].astype(ct), 0, m, M)
+    dy = _mask_tail(dy_ref[...].astype(ct), 0, m, M)
     acc_ref[...] += jax.lax.dot_general(
         x, dy, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -276,14 +415,16 @@ def _matmul_dw_kernel(x_ref, dy_ref, dw_ref, acc_ref, *, nm: int,
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def matmul_dw(x: Array, dy: Array, *, bm: int = 256, bn: int = 256,
-              bk: int = 512, interpret: bool = False) -> Array:
+def matmul_dw(x: Array, dy: Array, *, bm: int | None = None,
+              bn: int | None = None, bk: int | None = None,
+              interpret: bool = False) -> Array:
     """dw = xᵀ @ dy in f32 (VMEM scratch accumulation over the M loop).
     x: (M,K); dy: (M,N); out (K,N) f32."""
     M, K = x.shape
     M2, N = dy.shape
     assert M == M2, (x.shape, dy.shape)
-    bk, bn, bm = _clamp_block(bk, K), _clamp_block(bn, N), _clamp_block(bm, M)
+    bm, bn, bk, params = _pick_blocks("dw", M, K, N, bm, bn, bk, x.dtype,
+                                      dy.dtype, jnp.float32)
     grid = (pl.cdiv(K, bk), pl.cdiv(N, bn), pl.cdiv(M, bm))
     kernel = functools.partial(_matmul_dw_kernel, nm=grid[2],
                                dims=(M, K, N))
@@ -298,8 +439,7 @@ def matmul_dw(x: Array, dy: Array, *, bm: int = 256, bn: int = 256,
         out_shape=jax.ShapeDtypeStruct((K, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=params,
     )(x, dy)
 
 
@@ -371,7 +511,8 @@ def _fxp_qmatmul_kernel(ctl_ref, x_ref, w_ref, o_ref, acc_ref, *, nk: int,
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
                                              "out_dtype"))
 def fxp_qmatmul(x: Array, w: Array, seed: Array, fl: Array, mode: Array, *,
-                bm: int = 256, bn: int = 256, bk: int = 512, out_dtype=None,
+                bm: int | None = None, bn: int | None = None,
+                bk: int | None = None, out_dtype=None,
                 interpret: bool = False) -> Array:
     """y = x @ (Q⟨8,fl⟩(w) · 2^-fl), quantizing ``w`` in the matmul
     prologue. x: (M,K) float; w: (K,N) float MASTER; seed/fl/mode: int32
@@ -381,7 +522,8 @@ def fxp_qmatmul(x: Array, w: Array, seed: Array, fl: Array, mode: Array, *,
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
     out_dtype = out_dtype or x.dtype
-    bm, bn, bk = _clamp_block(bm, M), _clamp_block(bn, N), _clamp_block(bk, K)
+    bm, bn, bk, params = _pick_blocks("qfwd", M, K, N, bm, bn, bk, x.dtype,
+                                      w.dtype, out_dtype)
     grid = (pl.cdiv(M, bm), pl.cdiv(N, bn), pl.cdiv(K, bk))
     kernel = functools.partial(_fxp_qmatmul_kernel, nk=grid[2],
                                dims=(M, K, N))
@@ -399,8 +541,7 @@ def fxp_qmatmul(x: Array, w: Array, seed: Array, fl: Array, mode: Array, *,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=params,
     )(ctl, x, w)
 
 
@@ -437,14 +578,16 @@ def _matmul_qdx_kernel(ctl_ref, dy_ref, w_ref, dx_ref, acc_ref, *, nn: int,
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
                                              "out_dtype"))
 def matmul_qdx(dy: Array, w: Array, seed: Array, fl: Array, mode: Array, *,
-               bm: int = 256, bn: int = 256, bk: int = 512, out_dtype=None,
+               bm: int | None = None, bn: int | None = None,
+               bk: int | None = None, out_dtype=None,
                interpret: bool = False) -> Array:
     """dx = dy @ (Q⟨8,fl⟩(w)·2^-fl)ᵀ.  dy: (M,N); w: (K,N) float master."""
     M, N = dy.shape
     K, N2 = w.shape
     assert N == N2, (dy.shape, w.shape)
     out_dtype = out_dtype or dy.dtype
-    bm, bk, bn = _clamp_block(bm, M), _clamp_block(bk, K), _clamp_block(bn, N)
+    bm, bn, bk, params = _pick_blocks("qdx", M, K, N, bm, bn, bk, dy.dtype,
+                                      w.dtype, out_dtype)
     grid = (pl.cdiv(M, bm), pl.cdiv(K, bk), pl.cdiv(N, bn))
     kernel = functools.partial(_matmul_qdx_kernel, nn=grid[2],
                                dims=(M, K, N))
@@ -462,8 +605,7 @@ def matmul_qdx(dy: Array, w: Array, seed: Array, fl: Array, mode: Array, *,
         out_shape=jax.ShapeDtypeStruct((M, K), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
         interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=params,
     )(ctl, dy, w)
 
 
@@ -496,8 +638,9 @@ def _fxp_matmul_diff_bwd(cfg, res, dy):
 _fxp_matmul_diff.defvjp(_fxp_matmul_diff_fwd, _fxp_matmul_diff_bwd)
 
 
-def fxp_matmul_vjp(x: Array, wq: Array, scale: Array, *, bm: int = 256,
-                   bn: int = 256, bk: int = 512, out_dtype=None,
+def fxp_matmul_vjp(x: Array, wq: Array, scale: Array, *,
+                   bm: int | None = None, bn: int | None = None,
+                   bk: int | None = None, out_dtype=None,
                    interpret: bool = False) -> Array:
     """Differentiable :func:`fxp_matmul`: same forward kernel, Pallas
     backward (``matmul_dx`` / ``matmul_dw``)."""
@@ -534,8 +677,8 @@ _int8_matmul_diff.defvjp(_int8_matmul_diff_fwd, _int8_matmul_diff_bwd)
 
 
 def int8_matmul_vjp(xq: Array, wq: Array, sx: Array, sw: Array, *,
-                    bm: int = 256, bn: int = 256, bk: int = 512,
-                    interpret: bool = False) -> Array:
+                    bm: int | None = None, bn: int | None = None,
+                    bk: int | None = None, interpret: bool = False) -> Array:
     """Differentiable :func:`int8_matmul` (scale cotangents only; the int8
     words are non-differentiable storage)."""
     return _int8_matmul_diff((bm, bn, bk, interpret), xq, wq,
@@ -580,8 +723,9 @@ _fxp_dense_diff.defvjp(_fxp_dense_diff_fwd, _fxp_dense_diff_bwd)
 
 
 def fxp_dense_vjp(x: Array, wq: Array, scale: Array, wref: Array, *,
-                  bm: int = 256, bn: int = 256, bk: int = 512,
-                  out_dtype=None, interpret: bool = False) -> Array:
+                  bm: int | None = None, bn: int | None = None,
+                  bk: int | None = None, out_dtype=None,
+                  interpret: bool = False) -> Array:
     """Differentiable dense layer over MATERIALIZED int8 words: forward is
     :func:`fxp_matmul`, dx streams the same int8 tiles (``matmul_dx``), and
     dw = xᵀ@dy (``matmul_dw``) lands on ``wref`` — the straight-through
@@ -616,8 +760,9 @@ _fxp_qdense_diff.defvjp(_fxp_qdense_diff_fwd, _fxp_qdense_diff_bwd)
 
 
 def fxp_qdense_vjp(x: Array, w: Array, seed: Array, fl: Array, mode: Array,
-                   *, bm: int = 256, bn: int = 256, bk: int = 512,
-                   out_dtype=None, interpret: bool = False) -> Array:
+                   *, bm: int | None = None, bn: int | None = None,
+                   bk: int | None = None, out_dtype=None,
+                   interpret: bool = False) -> Array:
     """Differentiable quantize-prologue dense layer: forward is
     :func:`fxp_qmatmul` (master in, words only ever in VMEM), dx is
     :func:`matmul_qdx` (same index-hash words, recomputed in-register), and

@@ -180,8 +180,8 @@ def fxp_matmul(x: Array, wq: Array, scale: Array, *, use_pallas: bool = False,
     dequantized HBM weight copy.
 
     Masking contract: ANY ⟨M,K,N⟩ is accepted — primes included. Blocks
-    are the requested size clamped to the dim (never a whole-dim
-    fallback), grids are ``pl.cdiv``, and partial boundary blocks are
+    are the shape rule's (``fxp_matmul._dense_blocks``) clamped to the
+    dim, grids are ``pl.cdiv``, and partial boundary blocks are
     correct by construction: the forward and both backward kernels zero
     the contracted-dim tail lanes in-register before each MXU
     accumulation and zero-fill the valid slice on boundary writes

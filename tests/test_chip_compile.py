@@ -6,8 +6,10 @@ break the (8, 128) tiling rule, too much VMEM). These tests lower each kernel
 of the AdaPT train step with ``interpret=False`` for one chip of a described
 ``v5e:2x2`` topology and compile it with the installed TPU compiler — no chip
 is needed, and nothing runs. Widths are SmolLM-360M's (d=960, ff=2560, 15/5
-heads of 64, vocab 49152) at seq 2048 and global batch 8: d_model is not a
-multiple of the 256/512 blocks, so the tail-masked boundary blocks compile too.
+heads of 64, vocab 49152) at seq 2048 and global batch 8, and the dense
+products of the Granite-8B cut at the same rows, each with the blocks and
+VMEM limit the shape rule picks; decode rows with a prime K compile the
+tail-masked boundary blocks.
 
 The topology is described inside a module fixture: only the worker that runs
 this file loads the TPU compiler library.
@@ -31,6 +33,13 @@ M = BATCH * SEQ
 # (K, N) of every dense layer: q/o projections, k/v projections, MLP in, MLP
 # out, LM head.
 DENSE_SHAPES = [(D, D), (D, HKV * DH), (D, FF), (FF, D), (D, VOCAB)]
+# The same for Granite-8B-Code's widths (d 4096, 8 kv heads of 128, ff 14336)
+# with a quarter of its vocabulary, at the same M = 16,384 rows (seq 4096,
+# global batch 4): the blocks the shape rule picks for wide products, and
+# the VMEM limit it sets for them, must compile too.
+G_D, G_KV, G_FF, G_VOCAB = 4096, 8 * 128, 14336, 12288
+GRANITE_DENSE_SHAPES = [(G_D, G_D), (G_D, G_KV), (G_D, G_FF), (G_FF, G_D),
+                        (G_D, G_VOCAB)]
 
 
 @pytest.fixture(scope="module")
@@ -107,7 +116,7 @@ def test_edf_ladder_hists(one_chip, per_layer):
              _spec(one_chip, lead, jnp.int32))
 
 
-@pytest.mark.parametrize("kn", DENSE_SHAPES)
+@pytest.mark.parametrize("kn", DENSE_SHAPES + GRANITE_DENSE_SHAPES)
 def test_fxp_dense_vjp_grad(one_chip, kn):
     K, N = kn
 
@@ -155,7 +164,7 @@ def test_flash_attention_forward(one_chip):
              _spec(one_chip, (BATCH, SEQ, HKV, DH), jnp.bfloat16))
 
 
-@pytest.mark.parametrize("kn", [(D, D), (D, VOCAB)])
+@pytest.mark.parametrize("kn", [(D, D), (D, VOCAB), (1031, FF)])
 def test_fxp_matmul_decode_rows(one_chip, kn):
     """Serving decode: one row per slot, the four batcher slots vmapped
     (``ContinuousBatcher._decode_fn``)."""

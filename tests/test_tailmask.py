@@ -272,11 +272,11 @@ def test_attention_blocks_are_clamp_never_whole_dim():
 
 
 def test_ops_fxp_matmul_prime_dims_default_blocks():
-    """The op-level wrapper (default 256/256/512 blocks) on prime dims:
-    blocks clamp to min(default, dim) — multi-block where the dim exceeds
-    the default, exact parity either way."""
+    """The op-level wrapper (the shape rule's blocks) on prime dims: blocks
+    are the rule's, clamped to the dim — multi-block where the dim exceeds
+    the rule's cap, exact parity either way."""
     k1, k2 = jax.random.split(KEY)
-    m, k, n = 509, 1031, 127        # M and K exceed the default blocks
+    m, k, n = 509, 1031, 127        # K exceeds the rule's contracted cap
     x = jax.random.normal(k1, (m, k), jnp.float32)
     wq = jax.random.randint(k2, (k, n), -128, 128, jnp.int8)
     s = jnp.float32(1 / 64)
@@ -288,7 +288,9 @@ def test_ops_fxp_matmul_prime_dims_default_blocks():
     jaxpr = jax.make_jaxpr(lambda a: ops.fxp_matmul(
         a, wq, s, use_pallas=True))(x).jaxpr
     (grid,) = jaxpr_tools.pallas_grids(jaxpr)
-    assert grid == (-(-m // 256), 1, -(-k // 512))
+    b = fm._dense_blocks("fwd", m, k, n, x.dtype, wq.dtype, x.dtype)
+    assert grid == (-(-m // b["M"]), -(-n // b["N"]), -(-k // b["K"]))
+    assert grid[2] > 1, f"single-block contraction {grid}"
 
 
 # ---------------------------------------------------------------------------
