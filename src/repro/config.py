@@ -33,6 +33,13 @@ class ModelConfig:
     attn_logit_softcap: float = 0.0               # gemma2: 50.0
     final_logit_softcap: float = 0.0              # gemma2: 30.0
     rope_theta: float = 10000.0
+    # YaRN rope on full-attention slots (window 0) when yarn_factor > 0;
+    # windowed slots keep plain rope at rope_theta
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 0                    # original_max_position_embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0            # cos/sin scale
     use_qk_norm: bool = False
     # layer kind pattern (cycled): attn | mamba | cross  — transformer block kind
     layer_pattern: Tuple[str, ...] = ("attn",)
@@ -43,6 +50,8 @@ class ModelConfig:
     moe_d_ff: int = 0                             # 0 -> d_ff
     dense_residual_d_ff: int = 0                  # arctic: parallel dense FFN
     capacity_factor: float = 1.25
+    experts_held: int = 0                         # 0 -> num_experts; else
+    expert_offset: int = 0                        # this device's share of them
     # SSM (mamba2 / SSD)
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -156,12 +165,14 @@ class QuantConfig:
     # Any layer shape is eligible — primes included: the gridded kernels
     # tail-mask partial boundary blocks in-register (no divisibility
     # restriction, no whole-dim VMEM fallback; tests/test_tailmask.py).
+    #   * the MoE experts' grouped products (models/moe.py): each expert's
+    #     int8 words with its own FL into kernels/ops.fxp_gmm (fwd, dx, dw
+    #     over rows sorted by expert; fixed_point.EXPERT_PARAM_NAMES).
     # Remaining exclusions: attention slots whose window arrives as a traced
-    # scalar (masked XLA path), the CNN family's conv forward, non-2-D
-    # quantized leaves that no dense layer consumes (embed tables, depthwise
-    # conv kernels, MoE expert einsum operands — dequantized at their use
-    # site as before; fixed_point.DENSE_PARAM_NAMES), and unevenly-sharded /
-    # RTN-mode quantize leaves (controller._use_fused_prng).
+    # scalar (masked XLA path), the CNN family's conv forward, quantized
+    # leaves that no kernel consumes (embed tables, depthwise conv kernels —
+    # dequantized at their use site), and unevenly-sharded / RTN-mode
+    # quantize leaves (controller._use_fused_prng).
     use_pallas: bool = False
     # fused_prng draws the stochastic-rounding noise INSIDE the quantize
     # kernel (hardware PRNG on TPU, counter-hash under interpret), so the

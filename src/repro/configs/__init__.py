@@ -18,6 +18,7 @@ _MODULES = {
     "zamba2-7b": "zamba2_7b",
     "mixtral-8x22b": "mixtral_8x22b",
     "arctic-480b": "arctic_480b",
+    "mellum2-12b": "mellum2_12b",
     "llama-3.2-vision-11b": "llama3_2_vision_11b",
     "hubert-xlarge": "hubert_xlarge",
     "mamba2-780m": "mamba2_780m",
@@ -63,5 +64,5 @@ def list_archs() -> List[str]:
 
 
 def assigned_archs() -> List[str]:
-    """The 10 assigned LM-family architectures (excludes paper CNNs/tiny)."""
+    """The assigned LM-family architectures (excludes paper CNNs/tiny)."""
     return [a for a in _MODULES if a not in ("alexnet", "resnet20", "tiny")]

@@ -4,14 +4,14 @@ State layout (a plain dict pytree → trivially checkpointable):
 
     state = {
       "tensors": { path: {
-          "wl":       int32 (L,) or ()     word length
-          "fl":       int32 (L,) or ()     fractional length
-          "lb":       int32 (L,) or ()     lookback
-          "res":      int32 (L,) or ()     EDF resolution
-          "count":    int32 (L,) or ()     optimizer steps in current window
-          "norm_sum": f32   (L,) or ()     Σ‖g_k‖₂ over window
+          "wl":       int32 P     word length
+          "fl":       int32 P     fractional length
+          "lb":       int32 P     lookback
+          "res":      int32 P     EDF resolution
+          "count":    int32 P     optimizer steps in current window
+          "norm_sum": f32   P     Σ‖g_k‖₂ over window
           "grad_sum": bf16  like param     Σ g_k over window
-          "sp":       f32   (L,) or ()     non-zero fraction at last switch
+          "sp":       f32   P     non-zero fraction at last switch
       }},
       "strategy":  int32 ()                 st ∈ {0:min, 1:mean, 2:max}
       "loss_hist": f32 (H,)                 ring buffer
@@ -19,11 +19,14 @@ State layout (a plain dict pytree → trivially checkpointable):
       "loss_seen": int32 ()
     }
 
-Leaves with a leading scanned-layer dim L (the "blocks" stack) carry per-layer
-precision; everything is vmapped over that dim. The hot ``train_step`` only
-*reads* wl/fl and *writes* the accumulators; ``precision_switch`` (PushDown +
-PushUp + adaptation) runs every ``adapt_interval`` steps on the same jit graph
-regardless of which tensors actually switch (masked updates).
+P, the precision's shape, is () for a leaf with one ⟨WL,FL⟩; leaves with a
+leading scanned-layer dim L (the "blocks" stack) carry per-layer precision,
+P = (L,), and MoE expert stacks (L, E, K, N) one per (layer, expert), P =
+(L, E), each expert's matrix its own tensor. Everything is vmapped over
+those dims. The hot ``train_step`` only *reads* wl/fl and *writes* the
+accumulators; ``precision_switch`` (PushDown + PushUp + adaptation) runs
+every ``adapt_interval`` steps on the same jit graph regardless of which
+tensors actually switch (masked updates).
 """
 from __future__ import annotations
 
@@ -65,7 +68,13 @@ def is_stacked(path: str) -> bool:
 
 
 def _per_layer_shape(path: str, leaf: Array):
-    return (leaf.shape[0],) if (is_stacked(path) and leaf.ndim >= 3) else ()
+    """Shape of the leaf's precision: (L,) for a stacked leaf, (L, E) for a
+    stacked expert leaf, () otherwise."""
+    if not (is_stacked(path) and leaf.ndim >= 3):
+        return ()
+    if fxp.is_expert_param(path) and leaf.ndim == 4:
+        return leaf.shape[:2]
+    return (leaf.shape[0],)
 
 
 def _reduce_axes(path: str, leaf: Array):
@@ -117,7 +126,7 @@ def accumulate(state: Dict[str, Any], grads: PyTree, loss: Array) -> Dict[str, A
     tensors = {}
     for path, ts in state["tensors"].items():
         g = flat[path].astype(jnp.float32)
-        axes = tuple(range(1, g.ndim)) if ts["wl"].shape else tuple(range(g.ndim))
+        axes = tuple(range(ts["wl"].ndim, g.ndim))
         gn = jnp.sqrt(jnp.sum(g * g, axis=axes) + 1e-30)
         tensors[path] = {
             **ts,
@@ -190,10 +199,16 @@ def _switch_tensor(ts: Dict[str, Array], w: Array, strategy: Array,
 
     gsum = ts["grad_sum"].astype(jnp.float32)
     if per_layer:
-        axes = tuple(range(1, gsum.ndim))
-        gsum_norm = jnp.sqrt(jnp.sum(gsum * gsum, axis=axes) + 1e-30)
-        outs = jax.vmap(one)(w, ts["wl"], ts["fl"], ts["lb"], ts["res"],
-                             ts["count"], ts["norm_sum"], gsum_norm, ts["sp"])
+        # one vmap over every (layer[, expert]) tensor of the stack
+        lead = ts["wl"].shape
+        flat = lambda a: a.reshape((-1,) + a.shape[len(lead):])
+        axes = tuple(range(1, gsum.ndim - len(lead) + 1))
+        gflat = flat(gsum)
+        gsum_norm = jnp.sqrt(jnp.sum(gflat * gflat, axis=axes) + 1e-30)
+        outs = jax.vmap(one)(flat(w), *(flat(ts[k]) for k in (
+            "wl", "fl", "lb", "res", "count", "norm_sum")), gsum_norm,
+            flat(ts["sp"]))
+        outs = tuple(o.reshape(lead) for o in outs)
     else:
         gsum_norm = jnp.sqrt(jnp.sum(gsum * gsum) + 1e-30)
         outs = one(w, ts["wl"], ts["fl"], ts["lb"], ts["res"],
@@ -281,9 +296,10 @@ def _use_dense_prologue(qcfg: QuantConfig, path: str, fl: Array,
     leaves ``models/common.dense`` actually feeds to the kernels — a 2-D
     weight (scalar ⟨WL,FL⟩) or a per-layer-stacked (L, K, N) weight with
     an (L,)-vector precision, named in ``fixed_point.DENSE_PARAM_NAMES``.
-    Everything else (embed tables, conv kernels, MoE expert einsum
-    operands) keeps the materialized packed container. Works for SR (per-
-    leaf/-layer seeds, portable index-hash stream) AND RTN (key=None /
+    Everything else (embed tables, conv kernels, MoE expert matrices, which
+    the grouped kernels read as words) keeps the materialized packed
+    container. Works for SR (per-leaf/-layer seeds, portable index-hash
+    stream) AND RTN (key=None /
     stochastic_rounding off → mode 0, bit-identical to ``jnp.round``),
     so serving takes the same path.
 
@@ -356,12 +372,12 @@ def quantize_params(params: PyTree, state: Dict[str, Any], qcfg: QuantConfig,
                 # to ~3% and NOT a power of two — fixed_point.pow2i
                 sc = fxp.pow2i(-fl).astype(jnp.bfloat16)
                 if fl.shape:
-                    sc = sc.reshape(fl.shape + (1,) * (leaf.ndim - 1))
+                    sc = sc.reshape(fl.shape + (1,) * (leaf.ndim - fl.ndim))
                 return q8.astype(jnp.bfloat16) * sc
             return kops.sr_quantize_fused(leaf, seed, wl, fl, use_pallas=True,
                                           sharding=sh).astype(out_dtype)
-        if wl.shape:  # stacked: broadcast (L,) -> (L,1,...)
-            bshape = wl.shape + (1,) * (leaf.ndim - 1)
+        if wl.shape:  # stacked: broadcast (L[, E]) -> (L[, E], 1, ...)
+            bshape = wl.shape + (1,) * (leaf.ndim - wl.ndim)
             wl = wl.reshape(bshape)
             fl = fl.reshape(bshape)
         u = None
@@ -422,7 +438,7 @@ def quantize_params_packed(params: PyTree, state: Dict[str, Any],
         lax.scan's leading-axis slicing."""
         sc = fxp.pow2i(-fl).astype(jnp.bfloat16)
         if fl.shape:
-            return sc.reshape(fl.shape + (1,) * (leaf.ndim - 1))
+            return sc.reshape(fl.shape + (1,) * (leaf.ndim - fl.ndim))
         if is_stacked(p) and leaf.ndim >= 2:
             return jnp.broadcast_to(sc.reshape((1,) * leaf.ndim),
                                     (leaf.shape[0],) + (1,) * (leaf.ndim - 1))
@@ -462,6 +478,16 @@ def quantize_params_packed(params: PyTree, state: Dict[str, Any],
                 wm = jax.lax.with_sharding_constraint(wm, sh)
             return {"wm": wm, "seed": seed, "flq": fl,
                     "mode": jnp.full(fl.shape, 1 if sr else 0, jnp.int32)}
+        if fl.ndim == 2 and sh is None and _use_fused_prng(
+                qcfg, key, fl.reshape(-1), leaf.reshape(
+                    (-1,) + leaf.shape[2:])):
+            # an (L, E) expert stack: one launch of the stacked kernel over
+            # its L·E matrices, each with its own FL
+            q8 = kops.sr_quantize_fused_int8(
+                leaf.reshape((-1,) + leaf.shape[2:]), _leaf_seed(key, p),
+                fl.reshape(-1), use_pallas=True).reshape(leaf.shape)
+            return {"q8": q8, "sc": _sc_for(p, leaf, fl),
+                    "wref": jnp.zeros(leaf.shape, jnp.bfloat16)}
         if _use_fused_prng(qcfg, key, fl, leaf, sh):
             # in-kernel PRNG: the int8 words are produced in one pass with
             # no noise operand — the packed wire payload never sees f32.
@@ -475,7 +501,7 @@ def quantize_params_packed(params: PyTree, state: Dict[str, Any],
                 wref = jax.lax.with_sharding_constraint(wref, sh)
             return {"q8": q8, "sc": sc, "wref": wref}
         if fl.shape:
-            fl = fl.reshape(fl.shape + (1,) * (leaf.ndim - 1))
+            fl = fl.reshape(fl.shape + (1,) * (leaf.ndim - fl.ndim))
         u = None
         if qcfg.stochastic_rounding and key is not None:
             u = fxp.uniform_noise_like(_leaf_key(key, p), leaf)

@@ -131,16 +131,28 @@ PACKED_KEYS = frozenset(("q8", "sc", "wref"))
 QDENSE_KEYS = frozenset(("wm", "seed", "flq", "mode"))
 
 # Param-tree leaf names consumed by models/common.dense (2-D x@W matmuls).
-# Only these are eligible for the kernel dense path — everything else that
-# quantizes (embed tables, depthwise conv kernels, MoE expert einsum
-# operands, d_skip) keeps the materialized packed container and is
-# dequantized at its use site exactly as before.
+# Only these are eligible for the kernel dense path. MoE expert matrices
+# (EXPERT_PARAM_NAMES) keep the materialized packed container and, under
+# use_pallas, reach the grouped kernels (kernels/ops.fxp_gmm) as int8
+# words; everything else that quantizes (embed tables, depthwise conv
+# kernels, d_skip) is dequantized at its use site.
 DENSE_PARAM_NAMES = frozenset((
     "wq", "wk", "wv", "wo",            # attention projections
     "wi_gate", "wi_up",                # gated-MLP in-projections
     "in_proj", "out_proj",             # SSM / audio-frontend projections
     "head",                            # LM head
 ))
+
+
+# MoE expert matrices, (L, E, K, N) stacks: each expert of each layer is
+# its own quantized tensor with its own <WL, FL> (controller), fed to the
+# grouped kernels under use_pallas (models/moe.py).
+EXPERT_PARAM_NAMES = frozenset(("we_gate", "we_up", "we_down"))
+
+
+def is_expert_param(path: str) -> bool:
+    """True when the (slash-joined) param path names an expert matrix."""
+    return path.rsplit("/", 1)[-1] in EXPERT_PARAM_NAMES
 
 
 def is_packed(leaf) -> bool:
@@ -204,9 +216,10 @@ def _is_quantized_dict(leaf) -> bool:
 def unpack_tree(tree, keep_dense: bool = False):
     """Dequantize every packed / prologue leaf in a (sub)tree; plain leaves
     pass. ``keep_dense=True`` leaves dicts whose path names a dense-layer
-    weight (``is_dense_param``) INTACT — the kernel dense path consumes
-    them directly (``models/common.dense``), so they must survive the
-    use-site unpack that every other quantized leaf still gets.
+    weight (``is_dense_param``) or an expert matrix (``is_expert_param``)
+    INTACT — the kernels consume them directly (``models/common.dense``,
+    ``models/moe.py``), so they must survive the use-site unpack that
+    every other quantized leaf still gets.
 
     If the sharding rules carry '#packed_slice_specs' (path-suffix →
     NamedSharding), the int8 payload is constrained to that (TP-only) spec
@@ -221,7 +234,7 @@ def unpack_tree(tree, keep_dense: bool = False):
             return leaf
         key = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                        for k in path)
-        if keep_dense and is_dense_param(key):
+        if keep_dense and (is_dense_param(key) or is_expert_param(key)):
             return leaf
         if is_qdense(leaf):
             return qdense_view(leaf["wm"], leaf["seed"], leaf["flq"],

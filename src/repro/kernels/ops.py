@@ -13,6 +13,7 @@ from jax.sharding import PartitionSpec as P
 from repro import sharding as shd
 from repro.kernels import edf_ladder as _el
 from repro.kernels import flash_attention as _fa
+from repro.kernels import fxp_gmm as _fg
 from repro.kernels import fxp_matmul as _fm
 from repro.kernels import kl_hist as _kh
 from repro.kernels import ref
@@ -220,6 +221,49 @@ def fxp_dense(x: Array, wq: Array, scale: Array, wref: Array, *,
     out = jnp.dot(x.astype(jnp.float32), wv,
                   preferred_element_type=jnp.float32)
     return out.astype(out_dtype or x.dtype)
+
+
+def fxp_gmm(x: Array, wq: Array, sc: Array, wref: Array, layout, *,
+            tile: int, use_pallas: bool = False, out_dtype=None) -> Array:
+    """The experts' grouped product over MATERIALIZED int8 words: ``x``
+    (M, K) holds the rows of every held expert sorted by expert as
+    ``fxp_gmm.row_layout`` laid them out at ``tile``; ``wq`` (G, K, N) int8
+    with each expert's dequant scale ``sc`` (G, 1, 1) = 2^-FL; ``wref``
+    (G, K, N) takes the straight-through weight cotangent. Under
+    ``use_pallas`` the grouped kernels (``fxp_gmm`` / ``gmm_dx`` /
+    ``gmm_dw``, each expert's FL as scalar prefetch); otherwise
+    ``ragged_dot`` on the dequantized words over the layout's group
+    spans."""
+    if use_pallas:
+        fl = fl_of_scale(sc)
+        return _fg.fxp_gmm_vjp(x, wq, fl, wref, layout, tile=tile,
+                               out_dtype=out_dtype, interpret=not _on_tpu())
+    wv = wq.astype(jnp.float32) * jax.lax.stop_gradient(
+        sc.astype(jnp.float32)) + wref.astype(jnp.float32)
+    return ragged_dot(x, wv, layout["sizes"]).astype(out_dtype or x.dtype)
+
+
+def fl_of_scale(sc: Array) -> Array:
+    """FL of each exact power-of-two scale 2^-FL, read from its exponent
+    bits: (G, ...) -> (G,) int32."""
+    bits = jax.lax.bitcast_convert_type(
+        sc.astype(jnp.float32).reshape(sc.shape[0]), jnp.int32)
+    return 127 - ((bits >> 23) & 0xFF)
+
+
+def ragged_dot(x: Array, w: Array, sizes: Array) -> Array:
+    """``jax.lax.ragged_dot`` with f32 accumulation: row block g of ``x``
+    (``sizes[g]`` rows, in order) times ``w[g]``; rows past the blocks
+    give zeros. A program lowered for the CPU upcasts the operands, as
+    ``models.common.einsum_f32`` does."""
+    def dot(x, w):
+        return jax.lax.ragged_dot(x, w, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    def upcast(x, w):
+        return dot(x.astype(jnp.float32), w.astype(jnp.float32))
+
+    return jax.lax.platform_dependent(x, w, cpu=upcast, default=dot)
 
 
 def fxp_qdense(x: Array, w: Array, seed: Array, fl: Array, mode: Array, *,

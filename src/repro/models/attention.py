@@ -46,7 +46,8 @@ def init_layer(key: Array, cfg: ModelConfig, num_layers: int,
 
 
 def _project_qkv(p, x, cfg: ModelConfig, positions: Optional[Array],
-                 rope_on: bool = True, use_pallas: bool = False):
+                 rope_on: bool = True, use_pallas: bool = False,
+                 full: bool = True):
     B, S, _ = x.shape
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     q = common.dense(x, p["wq"], use_pallas=use_pallas).reshape(B, S, h, dh)
@@ -56,8 +57,9 @@ def _project_qkv(p, x, cfg: ModelConfig, positions: Optional[Array],
         q = common.rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = common.rms_norm(k, p["k_norm"], cfg.norm_eps)
     if rope_on and positions is not None:
-        q = common.rope(q, positions, cfg.rope_theta)
-        k = common.rope(k, positions, cfg.rope_theta)
+        inv, scale = common.rope_for(cfg, full)
+        q = common.rope(q, positions, cfg.rope_theta, inv, scale)
+        k = common.rope(k, positions, cfg.rope_theta, inv, scale)
     q = sharding.shard(q, "batch", "q_seq", "heads", None)
     k = sharding.shard(k, "batch", "seq", "kv_heads", None)
     v = sharding.shard(v, "batch", "seq", "kv_heads", None)
@@ -74,8 +76,9 @@ def attend_full(p: Dict[str, Array], x: Array, cfg: ModelConfig,
     kernel needs a static window so the dynamic form uses the masked path.
     """
     h = common.rms_norm(x, p["pre_norm"], cfg.norm_eps)
-    q, k, v = _project_qkv(p, h, cfg, positions, use_pallas=use_pallas)
     static_window = isinstance(window, int)
+    q, k, v = _project_qkv(p, h, cfg, positions, use_pallas=use_pallas,
+                           full=static_window and window == 0)
     if use_pallas and static_window:
         out = ops.attention(q, k, v, causal=causal, window=window,
                             softcap=cfg.attn_logit_softcap, use_pallas=True)
@@ -151,7 +154,8 @@ def attend_decode(p: Dict[str, Array], x: Array, cfg: ModelConfig,
     h = common.rms_norm(x, p["pre_norm"], cfg.norm_eps)
     B = x.shape[0]
     pos = jnp.broadcast_to(t[None, None], (B, 1))
-    q, k, v = _project_qkv(p, h, cfg, pos, use_pallas=use_pallas)
+    q, k, v = _project_qkv(p, h, cfg, pos, use_pallas=use_pallas,
+                           full=isinstance(window, int) and window == 0)
     C = cache_k.shape[1]
     slot = (t % C).astype(jnp.int32)
     cache_k = jax.lax.dynamic_update_slice_in_dim(

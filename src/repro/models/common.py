@@ -1,6 +1,8 @@
 """Shared model building blocks (pure functions over param dicts)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -118,14 +120,57 @@ def _dense_quantized(x: Array, w: dict, use_pallas: bool) -> Array:
     return y2.reshape(lead + (y2.shape[-1],))
 
 
-def rope(x: Array, positions: Array, theta: float) -> Array:
-    """Rotary embedding. x: (..., S, H, D); positions: (..., S) int32."""
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> Array:
+    """YaRN's rotary frequencies (HF ``_compute_yarn_parameters``): each
+    pair i < dim/2 blends the extrapolated θ^(-2i/dim) and the interpolated
+    θ^(-2i/dim) / factor by a linear ramp between the correction dims
+    low = ⌊dim·ln(orig / (β_fast·2π)) / (2 ln θ)⌋ and high = ⌈the same at
+    β_slow⌉ (clamped to [0, dim - 1]): pairs below ``low`` keep the
+    extrapolated frequency, pairs past ``high`` take the interpolated one."""
+    half = dim // 2
+
+    def corr(beta):
+        return dim * math.log(original_max / (beta * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ext = jnp.exp(-jnp.log(theta) * jnp.arange(half, dtype=jnp.float32)
+                  / half)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return ext / factor * ramp + ext * (1.0 - ramp)
+
+
+def rope_for(cfg, full: bool):
+    """(inverse frequencies or None, cos/sin scale) of a slot's rotary
+    embedding: YaRN on full-attention slots where the model gives it,
+    plain rope at ``rope_theta`` otherwise."""
+    if not (full and cfg.yarn_factor > 0):
+        return None, 1.0
+    inv = yarn_inv_freq(cfg.resolved_head_dim, cfg.rope_theta,
+                        cfg.yarn_factor, cfg.yarn_original_max,
+                        cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    return inv, cfg.yarn_attention_factor
+
+
+def rope(x: Array, positions: Array, theta: float, inv_freq=None,
+         scale: float = 1.0) -> Array:
+    """Rotary embedding. x: (..., S, H, D); positions: (..., S) int32.
+    ``inv_freq`` (D/2,) replaces θ^(-2i/D) where given, and ``scale``
+    multiplies cos and sin (YaRN's attention factor)."""
     d = x.shape[-1]
     half = d // 2
-    freq = jnp.exp(-jnp.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    freq = (jnp.exp(-jnp.log(theta) * jnp.arange(half, dtype=jnp.float32)
+                    / half) if inv_freq is None else inv_freq)
     ang = positions[..., None].astype(jnp.float32) * freq       # (..., S, half)
     cos = jnp.cos(ang)[..., None, :]                             # (..., S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:2 * half]
     rot = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     if 2 * half < d:  # odd head dim: pass the tail through

@@ -25,6 +25,7 @@ per-layer precision at period granularity.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -169,15 +170,31 @@ def _slot_params(blocks, plan, i, slot, shared):
 
 def _apply_ffn(pffn, x, cfg, slot: Slot, shared, dropless: bool = False,
                use_pallas: bool = False):
+    """(x, (rows, largest)) for an MoE slot (``moe.apply``'s counts),
+    (x, None) for the others."""
     if slot.ffn == "none":
-        return x
+        return x, None
     if slot.shared:
         return (mlp.apply(shared["mlp"], x, cfg, use_pallas=use_pallas)
-                if "mlp" in (shared or {}) else x)
+                if "mlp" in (shared or {}) else x), None
     if slot.ffn == "moe":
         return moe.apply(pffn, x, cfg, dropless=dropless,
                          use_pallas=use_pallas)
-    return mlp.apply(pffn, x, cfg, use_pallas=use_pallas)
+    return mlp.apply(pffn, x, cfg, use_pallas=use_pallas), None
+
+
+def _checkpoint_slots(remat: str, plan) -> bool:
+    """Whether ``remat="full"`` checkpoints each slot of the period on its
+    own rather than the period whole. Either way the backward recomputes
+    the period's forward; slot by slot it holds one slot's activations at a
+    time, not all of them, at the price of saving each slot's input. That
+    pays for a period with MoE slots, whose grouped buffers hold k rows per
+    token (Mellum2's 4-layer step, compiled for a v5e: 15.5 -> 11.6 GiB;
+    ``tests/test_chip_compile.py::test_remat_granularity_moe_period``).
+    For periods of dense or SSM slots it went either way with the shapes,
+    so they keep the whole-period checkpoint."""
+    return (remat == "full" and len(plan) > 1
+            and any(slot.ffn == "moe" for slot in plan))
 
 
 def _maybe_qact(x, act_wl, name, enabled):
@@ -191,8 +208,12 @@ def forward(params: Dict[str, Any], cfg: ModelConfig, *,
             embeds: Optional[Array] = None,
             memory: Optional[Array] = None,
             act_wl: Optional[Dict[str, Array]] = None,
-            use_pallas: bool = False, remat: str = "none") -> Array:
-    """Full-sequence forward → logits (B, S, V).
+            use_pallas: bool = False, remat: str = "none",
+            with_moe_rows: bool = False):
+    """Full-sequence forward → logits (B, S, V); with ``with_moe_rows``,
+    (logits, {"moe_rows_held": rows routed to held experts summed over the
+    MoE layers, "moe_rows_max": the most rows one held expert took in one
+    layer}), both int32 and 0 in a model without experts.
 
     tokens: (B, S) int32 for LM archs; embeds: (B, S, D) for the audio stub;
     memory: (B, M, D) precomputed image-patch embeddings for cross slots.
@@ -218,38 +239,55 @@ def forward(params: Dict[str, Any], cfg: ModelConfig, *,
     # period-stacked xs for the scan (block params + per-period act WLs)
     xs = (params["blocks"], act_wl if act_wl is not None else {})
 
+    has_moe = any(slot.ffn == "moe" for slot in plan)
+
+    def layer(i, slot, x, pslice, awl):
+        """Slot i of the period: (x, the MoE slot's counts or None)."""
+        if slot.kind == "mamba":
+            x = ssm.apply(pslice[slot_key(i, slot)], x, cfg,
+                          use_pallas=use_pallas)
+        elif slot.kind == "cross":
+            p = _slot_params(pslice, plan, i, slot, shared)
+            mem_k, mem_v = attention.project_memory(
+                p, memory, cfg, use_pallas=use_pallas)
+            x = attention.cross_attend(p, x, cfg, mem_k, mem_v,
+                                       use_pallas=use_pallas)
+        else:
+            p = _slot_params(pslice, plan, i, slot, shared)
+            x, _ = attention.attend_full(
+                p, x, cfg, positions, window=slot.window, causal=causal,
+                use_pallas=use_pallas)
+        counts = None
+        if slot.ffn != "none":
+            pffn = None if slot.shared else pslice[ffn_key(i, slot)]
+            x, counts = _apply_ffn(pffn, x, cfg, slot, shared,
+                                   use_pallas=use_pallas)
+        return _maybe_qact(x, awl, slot_key(i, slot), act_wl is not None), \
+            counts
+
+    per_slot = _checkpoint_slots(remat, plan)
+    layers = [functools.partial(layer, i, slot) for i, slot in
+              enumerate(plan)]
+    if per_slot:
+        layers = [jax.checkpoint(f) for f in layers]
+
     def body(x, xs_slice):
         pslice, awl = xs_slice
         pslice = _unpack(pslice, keep_dense=use_pallas)
-        for i, slot in enumerate(plan):
-            if slot.kind == "mamba":
-                x = ssm.apply(pslice[slot_key(i, slot)], x, cfg,
-                              use_pallas=use_pallas)
-            elif slot.kind == "cross":
-                p = _slot_params(pslice, plan, i, slot, shared)
-                mem_k, mem_v = attention.project_memory(
-                    p, memory, cfg, use_pallas=use_pallas)
-                x = attention.cross_attend(p, x, cfg, mem_k, mem_v,
-                                           use_pallas=use_pallas)
-            else:
-                p = _slot_params(pslice, plan, i, slot, shared)
-                x, _ = attention.attend_full(
-                    p, x, cfg, positions, window=slot.window, causal=causal,
-                    use_pallas=use_pallas)
-            if slot.ffn != "none":
-                pffn = None if slot.shared else pslice[ffn_key(i, slot)]
-                x = _apply_ffn(pffn, x, cfg, slot, shared,
-                               use_pallas=use_pallas)
-            x = _maybe_qact(x, awl, slot_key(i, slot), act_wl is not None)
-        return x, None
+        rows = (jnp.int32(0), jnp.int32(0))
+        for f in layers:
+            x, counts = f(x, pslice, awl)
+            if counts is not None:
+                rows = (rows[0] + counts[0], jnp.maximum(rows[1], counts[1]))
+        return x, (rows if has_moe else None)
 
-    if remat == "full":
+    if remat == "full" and not per_slot:
         body = jax.checkpoint(body)
     elif remat == "selective":
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     with jax.named_scope("adapt.layers"):
-        x, _ = jax.lax.scan(body, x, xs)
+        x, rows = jax.lax.scan(body, x, xs)
 
     with jax.named_scope("adapt.head"):
         x = common.rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -261,7 +299,12 @@ def forward(params: Dict[str, Any], cfg: ModelConfig, *,
                                   use_pallas=use_pallas)
         logits = common.softcap(logits.astype(jnp.float32),
                                 cfg.final_logit_softcap)
-        return sharding.shard(logits, "batch", "seq", "vocab")
+        logits = sharding.shard(logits, "batch", "seq", "vocab")
+    if with_moe_rows:
+        held, largest = rows or (jnp.zeros((1,), jnp.int32),) * 2
+        return logits, {"moe_rows_held": jnp.sum(held),
+                        "moe_rows_max": jnp.max(largest)}
+    return logits
 
 
 # ---------------------------------------------------------------------------
@@ -360,8 +403,8 @@ def decode_step(params: Dict[str, Any], cfg: ModelConfig, token: Array,
                 new_c[key] = {"k": ck, "v": cv}
             if slot.ffn != "none":
                 pffn = None if slot.shared else pslice[ffn_key(i, slot)]
-                x = _apply_ffn(pffn, x, cfg, slot, shared, dropless=True,
-                               use_pallas=use_pallas)
+                x, _ = _apply_ffn(pffn, x, cfg, slot, shared, dropless=True,
+                                  use_pallas=use_pallas)
             x = _maybe_qact(x, awl, key, act_wl is not None)
         return x, new_c
 
@@ -436,8 +479,8 @@ def prefill(params: Dict[str, Any], cfg: ModelConfig, tokens: Array, *,
                                "v": _roll_into_cache(v, C).astype(cache_dtype)}
             if slot.ffn != "none":
                 pffn = None if slot.shared else pslice[ffn_key(i, slot)]
-                x = _apply_ffn(pffn, x, cfg, slot, shared,
-                               use_pallas=use_pallas)
+                x, _ = _apply_ffn(pffn, x, cfg, slot, shared,
+                                  use_pallas=use_pallas)
             x = _maybe_qact(x, awl, key, act_wl is not None)
         return x, caches
 

@@ -38,17 +38,26 @@ def init_opt_state(params: PyTree, ocfg: OptimizerConfig) -> Dict[str, Any]:
     return state
 
 
-def _normalize(g: Array) -> Array:
-    n = jnp.sqrt(jnp.sum(jnp.square(g.astype(jnp.float32))))
+def _normalize(g: Array, axes=None) -> Array:
+    n = jnp.sqrt(jnp.sum(jnp.square(g.astype(jnp.float32)), axis=axes,
+                         keepdims=axes is not None))
     return (g / jnp.maximum(n, 1e-12)).astype(g.dtype)
 
 
 def normalize_grads(grads: PyTree, quantized_paths: Set[str]) -> PyTree:
-    """Per-tensor L2 normalization on AdaPT-quantized tensors (paper §3.3)."""
+    """Per-tensor L2 normalization on AdaPT-quantized tensors (paper §3.3).
+    A stacked MoE expert leaf (L, E, K, N) is L·E matrices, each divided
+    by its own norm."""
+    from repro.core import fixed_point as fxp
     from repro.core.controller import path_str
 
     def visit(path, g):
-        return _normalize(g) if path_str(path) in quantized_paths else g
+        p = path_str(path)
+        if p not in quantized_paths:
+            return g
+        if fxp.is_expert_param(p) and g.ndim == 4:
+            return _normalize(g, axes=(2, 3))
+        return _normalize(g)
 
     return jax.tree_util.tree_map_with_path(visit, grads)
 

@@ -93,18 +93,21 @@ def _task_loss(cfg: Config, qparams, stats, batch, act_wl=None,
     # models/common.dense, which streams int8 weight tiles into the fxp
     # matmul kernels (dx via the same tiles transposed, straight-through
     # dw = xᵀ@dy onto the master; tests/test_dense_path.py pins fwd+dx+dw
-    # per dense layer and zero dequantized-weight XLA matmuls). Remaining
+    # per dense layer and zero dequantized-weight XLA matmuls), and the MoE
+    # experts through the grouped kernels (kernels/ops.fxp_gmm). Remaining
     # exclusions: dynamic-window attention slots (traced window → masked
     # XLA path in attend_full), the CNN family's conv forward, and
-    # non-dense quantized leaves (embed/conv/MoE-expert weights —
-    # dequantized at their use site; fixed_point.DENSE_PARAM_NAMES).
+    # quantized leaves no kernel consumes (embed/conv weights — dequantized
+    # at their use site).
     with jax.named_scope("adapt.forward"):
-        logits = transformer.forward(qparams, m, act_wl=act_wl,
-                                     use_pallas=cfg.quant.use_pallas,
-                                     remat=cfg.train.remat, **kwargs)
+        out = transformer.forward(qparams, m, act_wl=act_wl,
+                                  use_pallas=cfg.quant.use_pallas,
+                                  remat=cfg.train.remat,
+                                  with_moe_rows=bool(m.num_experts), **kwargs)
+    logits, aux = out if m.num_experts else (out, {})
     with jax.named_scope("adapt.loss"):
         loss = transformer.lm_loss(logits, targets, shift=shift)
-    return loss, {"stats": stats}
+    return loss, {"stats": stats, **aux}
 
 
 # ---------------------------------------------------------------------------
@@ -205,8 +208,11 @@ def make_train_step(cfg: Config, qparam_shardings=None,
                     mb_batch)
                 inv = 1.0 / tcfg.accum_steps
                 g = jax.tree.map(lambda x: (x * inv).astype(jnp.float32), g)
-                return loss * inv, task * inv, \
-                    jax.tree.map(lambda a: a[-1], auxes), g
+                aux = jax.tree.map(lambda a: a[-1], auxes)
+                if "moe_rows_held" in aux:   # counted over the microbatches
+                    aux.update(moe_rows_held=jnp.sum(auxes["moe_rows_held"]),
+                               moe_rows_max=jnp.max(auxes["moe_rows_max"]))
+                return loss * inv, task * inv, aux, g
             (loss, (task, aux)), g = grad_fn(qp, b)
             return loss, task, aux, strip(g)
 
@@ -239,6 +245,10 @@ def make_train_step(cfg: Config, qparam_shardings=None,
             with jax.named_scope("adapt.grad_sync"):
                 loss, task, grads = jax.lax.pmean((loss, task, grads),
                                                   dp_axes)
+                if "moe_rows_held" in aux:
+                    aux = dict(aux, moe_rows_held=jax.lax.psum(
+                        aux["moe_rows_held"], dp_axes), moe_rows_max=
+                        jax.lax.pmax(aux["moe_rows_max"], dp_axes))
 
         if qcfg.mode != "off":
             with jax.named_scope("adapt.accumulate"):
@@ -253,8 +263,9 @@ def make_train_step(cfg: Config, qparam_shardings=None,
 
         metrics = {"loss": task, "full_loss": loss, "lr": opt["lr"],
                    "grad_norm": grad_norm}
-        if "acc" in aux:
-            metrics["acc"] = aux["acc"]
+        for k in ("acc", "moe_rows_held", "moe_rows_max"):
+            if k in aux:
+                metrics[k] = aux[k]
         new_state = {
             "params": params,
             "stats": aux.get("stats", state["stats"]),

@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.pushdown import WL_LADDER
 from repro.kernels import edf_ladder as el
 from repro.kernels import flash_attention as fa
+from repro.kernels import fxp_gmm as fg
 from repro.kernels import fxp_matmul as fm
 from repro.kernels import sr_quantize as sq
 
@@ -40,6 +41,11 @@ DENSE_SHAPES = [(D, D), (D, HKV * DH), (D, FF), (FF, D), (D, VOCAB)]
 G_D, G_KV, G_FF, G_VOCAB = 4096, 8 * 128, 14336, 12288
 GRANITE_DENSE_SHAPES = [(G_D, G_D), (G_D, G_KV), (G_D, G_FF), (G_FF, G_D),
                         (G_D, G_VOCAB)]
+# Mellum2's experts (d 2304, expert width 896), 32 held of 64, top-8, at
+# seq 8192 and global batch 2: the grouped kernels over the buffer of
+# 131,072 assignment rows laid out at the row tile the rule picks.
+MEL_D, MEL_F, MEL_E, MEL_ROWS = 2304, 896, 32, 16384 * 8
+MEL_SHAPES = [(MEL_D, MEL_F), (MEL_F, MEL_D)]
 
 
 @pytest.fixture(scope="module")
@@ -174,3 +180,64 @@ def test_fxp_matmul_decode_rows(one_chip, kn):
     _compile(fn, _spec(one_chip, (4, 1, K), jnp.bfloat16),
              _spec(one_chip, (K, N), jnp.int8),
              _spec(one_chip, (), jnp.float32))
+
+
+@pytest.mark.parametrize("kn", MEL_SHAPES)
+def test_grouped_kernels_vjp_grad(one_chip, kn):
+    """``fxp_gmm`` forward, ``gmm_dx`` and ``gmm_dw`` at Mellum2's expert
+    shapes (gate/up K 2304 / N 896, down K 896 / N 2304), 32 groups."""
+    K, N = kn
+    tile = fg.row_tile(MEL_ROWS / 64)
+    M = -(-MEL_ROWS // tile) * tile + MEL_E * tile
+    nt = M // tile
+
+    def loss(x, wq, fl, wref, tg, start, rows, live):
+        lay = dict(tile_group=tg, start=start, rows=rows, live=live)
+        y = fg.fxp_gmm_vjp(x, wq, fl, wref, lay, tile=tile, interpret=False)
+        return jnp.sum(y.astype(jnp.float32))
+
+    i32 = lambda *shape: _spec(one_chip, shape, jnp.int32)
+    text = _compile(jax.grad(loss, argnums=(0, 3)),
+                    _spec(one_chip, (M, K), jnp.bfloat16),
+                    _spec(one_chip, (MEL_E, K, N), jnp.int8),
+                    i32(MEL_E), _spec(one_chip, (MEL_E, K, N), jnp.bfloat16),
+                    i32(nt), i32(MEL_E), i32(MEL_E), i32(1))
+    for name in ("fxp_gmm", "gmm_dx", "gmm_dw"):
+        assert name in text, name
+
+
+def test_remat_granularity_moe_period(one_chip, monkeypatch):
+    """``remat="full"`` checkpoints a period with MoE slots slot by slot:
+    the compiled step's temporaries fall below those of the whole-period
+    checkpoint. The smoke Mellum2 model cut to one (windowed, full) period,
+    at 8,192 tokens a step."""
+    import dataclasses
+
+    from repro.config import apply_overrides
+    from repro.configs import get_smoke_config
+    from repro.kernels import ops
+    from repro.models import transformer
+    from repro.train import train_loop
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = get_smoke_config("mellum2-12b")
+    m = dataclasses.replace(cfg.model, num_layers=2,
+                            attn_pattern=("local", "global"))
+    cfg = apply_overrides(dataclasses.replace(cfg, model=m), [
+        "quant.use_pallas=true", "quant.container_dtype=int8_packed",
+        "train.remat=full", "train.accum_steps=1", "train.seq_len=4096",
+        "train.global_batch=2"])
+    assert transformer._checkpoint_slots("full", transformer.build_plan(m)[0])
+
+    def temporaries(per_slot):
+        monkeypatch.setattr(transformer, "_checkpoint_slots",
+                            lambda remat, plan: per_slot)
+        place = lambda tree: jax.tree.map(
+            lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+        state = jax.eval_shape(lambda: train_loop.init_state(cfg))
+        batch = {"tokens": _spec(one_chip, (2, 4096), jnp.int32)}
+        step = jax.jit(train_loop.make_train_step(cfg), donate_argnums=0)
+        compiled = step.lower(place(state), batch).compile()
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    assert temporaries(True) < temporaries(False)

@@ -419,9 +419,9 @@ def test_hybrid_ssm_family_dense_kernels(prologue):
 
 
 def test_moe_family_dense_kernels():
-    """MoE: router is excluded (f32), expert einsum weights keep the
-    materialized container (dequantized at the einsum), but the shared
-    dense layers still take the kernel path."""
+    """MoE: router is excluded (f32), the expert matrices keep the
+    materialized container and go to the grouped kernels as int8 words,
+    and the shared dense layers take the dense kernel path."""
     m = ModelConfig(name="tiny-moe", family="moe", num_layers=2,
                     d_model=64, num_heads=4, num_kv_heads=2, d_ff=128,
                     vocab_size=128, num_experts=4, experts_per_token=2,
@@ -433,8 +433,10 @@ def test_moe_family_dense_kernels():
     assert bool(jnp.isfinite(metrics["loss"]))
     jaxpr = jax.make_jaxpr(train_loop.make_train_step(cfg))(
         state, train_loop.make_batch(cfg, 1)).jaxpr
-    # attn wq wk wv wo + head = 5 (FFN is MoE: expert einsums stay XLA)
+    # attn wq wk wv wo + head = 5 (the FFN is MoE: its experts run on the
+    # grouped kernels, dx and dw once per expert matrix of each layer)
     assert jaxpr_tools.count_pallas_calls(jaxpr, "_fxp_qmatmul_kernel") == 5
+    assert jaxpr_tools.count_pallas_calls(jaxpr, "gmm_dw") == 3
 
 
 # ---------------------------------------------------------------------------

@@ -3,6 +3,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.config import load_config
@@ -59,12 +60,50 @@ def test_smoke_train_step(arch):
     assert moved
 
 
+# Router logits may differ between the batched forward and the step-wise
+# decode by bf16 rounding of the hidden state (up to ~0.03 on the smoke
+# models); a token whose k-th and (k+1)-th logits lie closer than TIE may
+# pick other experts on the two paths, and nothing causally after it then
+# has to agree.
+TIE = 0.06
+
+
+def _record_routing(monkeypatch):
+    """Patch ``moe.route`` to log each call's f32 router logits and chosen
+    experts, in execution order (layer by layer; step by step in decode)."""
+    from repro.models import moe
+    real, log = moe.route, []
+
+    def route(tokens, router, k):
+        weights, chosen = real(tokens, router, k)
+        logits = jnp.dot(tokens.astype(jnp.float32),
+                         router.astype(jnp.float32))
+        jax.debug.callback(
+            lambda l, c: log.append((np.asarray(l), np.asarray(c))),
+            logits, chosen, ordered=True)
+        return weights, chosen
+
+    monkeypatch.setattr(moe, "route", route)
+    return log
+
+
+def _routing(log, order):
+    """Stack logged calls to (layers, B, S, ...) arrays of logits and sorted
+    chosen experts; ``order`` turns the flat call list into that layout."""
+    jax.effects_barrier()
+    logits = order(np.stack([l for l, _ in log]))
+    chosen = order(np.stack([np.sort(c, axis=-1) for _, c in log]))
+    log.clear()
+    return logits, chosen
+
+
 @pytest.mark.parametrize("arch", [a for a in ARCHS
                                   if not get_smoke_config(a).model.is_encoder])
-def test_decode_matches_forward(arch):
+def test_decode_matches_forward(arch, monkeypatch):
     m = get_smoke_config(arch).model
     if m.num_experts:  # compare dropless-to-dropless
         m = dataclasses.replace(m, capacity_factor=16.0)
+        log = _record_routing(monkeypatch)
     params = transformer.init_params(jax.random.PRNGKey(0), m)
     B, S = 2, 8
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, m.vocab_size)
@@ -73,6 +112,9 @@ def test_decode_matches_forward(arch):
         kw["memory"] = jax.random.normal(jax.random.PRNGKey(2),
                                          (B, m.num_image_tokens, m.d_model))
     full = transformer.forward(params, m, tokens=toks, **kw)
+    if m.num_experts:
+        f_logits, f_chosen = _routing(
+            log, lambda a: a.reshape(a.shape[0], B, S, -1))
     caches = transformer.init_caches(m, B, S, dtype=jnp.float32)
     if m.cross_attn_every:
         from repro.models import attention
@@ -103,7 +145,28 @@ def test_decode_matches_forward(arch):
     else:
         tol = 0.05   # bf16 block compute: contraction order differs between
                      # the batched forward and the step-wise decode einsums
-    assert float(jnp.max(jnp.abs(dec - full))) < tol
+    compared = np.ones((B, S), bool)
+    if m.num_experts:
+        # decode logs (S steps x layers) calls of (B, ...) each
+        d_logits, d_chosen = _routing(
+            log, lambda a: np.moveaxis(a.reshape(S, -1, *a.shape[1:]), 0, 2))
+        k = m.experts_per_token
+        top = -np.sort(-f_logits, axis=-1)
+        margin = top[..., k - 1] - top[..., k]                  # (L, B, S)
+        differs = np.any(f_chosen != d_chosen, axis=-1)         # (L, B, S)
+        for b in range(B):
+            flips = np.nonzero(differs[:, b].any(axis=0))[0]
+            if flips.size:
+                # the first position routed apart sits on a near-tie, at the
+                # first layer it parts; later positions attend to it
+                t = flips[0]
+                layer = np.nonzero(differs[:, b, t])[0][0]
+                assert margin[layer, b, t] < TIE, (layer, b, t)
+                compared[b, t:] = False
+        assert compared.sum() >= B * S // 2
+        assert np.all(np.abs(f_logits - d_logits)[:, compared] < TIE / 2)
+    err = np.abs(np.asarray(dec - full, np.float32)).max(axis=-1)
+    assert float(err[compared].max()) < tol
 
 
 @pytest.mark.parametrize("arch", [a for a in ARCHS
