@@ -9,7 +9,8 @@ seq 2048, random weights from a seed).
 One chip, through the normal entry points:
   0. the hardware-PRNG quantize words (on the SR grid, unbiased,
      deterministic per seed) and compiled kernels with partial boundary
-     blocks (finite, on their oracles);
+     blocks (finite, on their oracles); each attention slot's live and
+     total flash blocks;
   1. the step-0 logits and loss against the plain float32 reference
      (``models/reference.py``, matmuls at "highest" precision) on the
      dequantized words the step draws;
@@ -327,11 +328,28 @@ def serve_phase(label, cfg, state):
     check(all(len(r.output) == 16 for r in done), "short generations")
 
 
+def attention_blocks(label, cfg):
+    """Each distinct attention slot's flash blocks at the step's sequence
+    (512-blocks, what ``ops.attention`` launches with): live, fully live
+    (computed without the mask) and all."""
+    from repro.kernels import flash_attention as fa
+    from repro.models import transformer
+
+    s = cfg.train.seq_len
+    slots, _ = transformer.build_plan(cfg.model)
+    for w in sorted({sl.window for sl in slots if sl.kind == "attn"}):
+        live, full, total = fa.block_counts(s, s, 512, 512, causal=True,
+                                            window=w)
+        log(label, f"flash blocks, window {w or 'full'} at seq {s}: live "
+            f"{live}/{total}, fully live {full}")
+
+
 def one_chip(label):
     from repro.train import train_loop
 
     kernel_phase(label)
     cfg = smollm_config()
+    attention_blocks(label, cfg)
     state = train_loop.init_state(cfg)
     ref_loss = reference_phase(label, cfg, state,
                                train_loop.make_batch(cfg, 0))

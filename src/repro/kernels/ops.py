@@ -313,7 +313,8 @@ def attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     contraction, and boundary writes carry zeros in the padding lanes.
     Aligned shapes trace to the exact unmasked kernels (zero overhead);
     causal/window/GQA masking composes with the tail mask through the one
-    shared ``_block_mask``."""
+    shared ``_block_mask``, and the launches visit only the blocks it
+    leaves live (``flash_attention.block_counts``)."""
     if use_pallas:
         return _fa.flash_attention_vjp(q, k, v, causal=causal, window=window,
                                        softcap=softcap, scale=scale,

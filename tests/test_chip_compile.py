@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import jaxpr_tools
 from repro.core.pushdown import WL_LADDER
 from repro.kernels import edf_ladder as el
 from repro.kernels import flash_attention as fa
@@ -46,6 +47,9 @@ GRANITE_DENSE_SHAPES = [(G_D, G_D), (G_D, G_KV), (G_D, G_FF), (G_FF, G_D),
 # 131,072 assignment rows laid out at the row tile the rule picks.
 MEL_D, MEL_F, MEL_E, MEL_ROWS = 2304, 896, 32, 16384 * 8
 MEL_SHAPES = [(MEL_D, MEL_F), (MEL_F, MEL_D)]
+# Mellum2's attention (32/4 heads of 128) at seq 8192, global batch 2: its
+# sliding-window slots (1024) run the banded flash launches.
+MEL_B, MEL_S, MEL_H, MEL_HKV, MEL_DH, MEL_WINDOW = 2, 8192, 32, 4, 128, 1024
 
 
 @pytest.fixture(scope="module")
@@ -168,6 +172,65 @@ def test_flash_attention_forward(one_chip):
     _compile(fn, _spec(one_chip, (BATCH, SEQ, H, DH), jnp.bfloat16),
              _spec(one_chip, (BATCH, SEQ, HKV, DH), jnp.bfloat16),
              _spec(one_chip, (BATCH, SEQ, HKV, DH), jnp.bfloat16))
+
+
+def _flash_grad(causal, window):
+    def loss(q, k, v):
+        o = fa.flash_attention_vjp(q, k, v, causal=causal, window=window,
+                                   interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _flash_eqns(fn, *args):
+    """{kernel name: pallas_call eqn} of the three flash launches."""
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    return dict(zip(jaxpr_tools.pallas_kernel_names(jaxpr),
+                    jaxpr_tools.pallas_eqns(jaxpr)))
+
+
+def test_flash_attention_window_band(one_chip):
+    """Mellum2's windowed slots: the forward, dQ and dK/dV launches walk
+    only the band of live blocks (3 of 16 at 512-blocks, at most the
+    ⌈(window + bq − 1) / bk⌉ + 1 the window allows), and compile."""
+    nq = MEL_S // 512
+    band = fa._band(nq, functools.partial(
+        fa._kv_blocks, bq=512, bk=512, causal=True, window=MEL_WINDOW,
+        q_offset=0, sq=MEL_S, skv=MEL_S))
+    assert band == 3 <= min(nq, -(-(MEL_WINDOW + 511) // 512) + 1)
+    args = (_spec(one_chip, (MEL_B, MEL_S, MEL_H, MEL_DH), jnp.bfloat16),
+            _spec(one_chip, (MEL_B, MEL_S, MEL_HKV, MEL_DH), jnp.bfloat16),
+            _spec(one_chip, (MEL_B, MEL_S, MEL_HKV, MEL_DH), jnp.bfloat16))
+    fn = _flash_grad(True, MEL_WINDOW)
+    grids = {name: tuple(eqn.params["grid_mapping"].grid)
+             for name, eqn in _flash_eqns(fn, *args).items()}
+    rep = MEL_H // MEL_HKV
+    assert grids == {
+        "_flash_kernel": (MEL_B, MEL_H, nq, band),
+        "_flash_dq_kernel": (MEL_B, MEL_H, nq, band),
+        "_flash_dkv_kernel": (MEL_B, MEL_HKV, nq, band * rep)}, grids
+    _compile(fn, *args)
+
+
+def test_flash_attention_noncausal_keeps_grid(one_chip):
+    """No causal mask and no window: every block is live and fully live,
+    so the launches keep the whole (B, H, nq, nk) grid and the kernels no
+    gate but their init and done."""
+    args = (_spec(one_chip, (BATCH, SEQ, H, DH), jnp.bfloat16),
+            _spec(one_chip, (BATCH, SEQ, HKV, DH), jnp.bfloat16),
+            _spec(one_chip, (BATCH, SEQ, HKV, DH), jnp.bfloat16))
+    fn = _flash_grad(False, 0)
+    n = SEQ // 512
+    eqns = _flash_eqns(fn, *args)
+    grids = {name: tuple(eqn.params["grid_mapping"].grid)
+             for name, eqn in eqns.items()}
+    assert grids == {"_flash_kernel": (BATCH, H, n, n),
+                     "_flash_dq_kernel": (BATCH, H, n, n),
+                     "_flash_dkv_kernel": (BATCH, HKV, n, n * H // HKV)}
+    for name, eqn in eqns.items():
+        body = eqn.params["jaxpr"]
+        assert jaxpr_tools.count_primitives(body, "cond") == 2, name
+    _compile(fn, *args)
 
 
 @pytest.mark.parametrize("kn", [(D, D), (D, VOCAB), (1031, FF)])

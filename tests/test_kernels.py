@@ -109,7 +109,7 @@ def test_kl_hist_matches_ref(n, bins):
                                     # prime seq dims: partial boundary
                                     # blocks in both grid dims (bq=bk=32)
                                     (127, 127), (131, 257)])
-@pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2)])
+@pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2), (6, 2), (8, 1)])
 def test_flash_attention_matches_ref(sq, skv, h, hkv):
     k1, k2, k3 = jax.random.split(KEY, 3)
     d = 64
@@ -122,7 +122,8 @@ def test_flash_attention_matches_ref(sq, skv, h, hkv):
                                atol=2e-3)
 
 
-@pytest.mark.parametrize("window", [16, 64])
+# 8 and 16 lie inside one 32-block; 80 spans several, 200 the whole sequence
+@pytest.mark.parametrize("window", [8, 16, 64, 80, 200])
 @pytest.mark.parametrize("softcap", [0.0, 30.0])
 def test_flash_attention_window_softcap(window, softcap):
     k1, k2, k3 = jax.random.split(KEY, 3)

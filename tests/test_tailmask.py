@@ -189,6 +189,8 @@ ATTN_TAIL_CASES = [
     dict(causal=False),
     dict(causal=True, window=37),
     dict(causal=True, window=50, softcap=15.0),
+    dict(causal=True, window=7),          # inside one block
+    dict(causal=True, window=90, softcap=15.0),
 ]
 
 

@@ -166,12 +166,16 @@ ATTN_CASES = [
     dict(causal=True, window=16),
     dict(causal=True, softcap=20.0),
     dict(causal=True, window=32, softcap=10.0),
+    dict(causal=True, window=5),          # inside one block
+    dict(causal=True, window=70),         # across three blocks
+    dict(causal=False, window=40),
 ]
 
 
 @pytest.mark.parametrize("kw", ATTN_CASES,
                          ids=[str(c) for c in ATTN_CASES])
-@pytest.mark.parametrize("b,h,hkv", [(1, 4, 4), (2, 8, 2)])
+@pytest.mark.parametrize("b,h,hkv", [(1, 4, 4), (2, 8, 2), (1, 6, 2),
+                                     (1, 8, 1)])
 def test_attention_grad_parity(b, h, hkv, kw):
     k1, k2, k3, k4 = jax.random.split(jax.random.fold_in(KEY, b * h), 4)
     q = jax.random.normal(k1, (b, 96, h, 32), jnp.float32)
@@ -187,7 +191,7 @@ def test_attention_grad_parity(b, h, hkv, kw):
 
 
 @pytest.mark.parametrize("sq,skv", [(64, 128), (32, 96), (96, 96),
-                                    (61, 131), (131, 257)])
+                                    (61, 131), (131, 257), (32, 160)])
 def test_attention_grad_parity_prefill_offset(sq, skv):
     """Sq ≠ Skv: query positions end-aligned to the key space. The prime
     rows run partial tail-masked boundary blocks in both grid dims."""
@@ -234,6 +238,59 @@ def test_attention_grad_parity_dead_rows():
                   (0, 1, 2))(q, k, v)
     for a, b_, name in zip(gp, gr, "qkv"):
         _close(a, b_, msg=f"d{name} with dead query rows")
+
+
+@pytest.mark.parametrize("sq,skv,window", [(32, 160, 40), (61, 131, 20),
+                                           (32, 160, 8)])
+def test_attention_grad_parity_prefill_offset_window(sq, skv, window):
+    """A causal window against a longer key space: the first kv blocks
+    hold no key any query reaches, so the dK/dV launch visits no q block
+    there and must still write exact zeros."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.fold_in(KEY, skv), 4)
+    q = jax.random.normal(k1, (1, sq, 8, 32), jnp.float32)
+    k = jax.random.normal(k2, (1, skv, 1, 32), jnp.float32)
+    v = jax.random.normal(k3, (1, skv, 1, 32), jnp.float32)
+    cot = jax.random.normal(k4, q.shape, jnp.float32)
+    f = lambda q, k, v: jnp.sum(ops.attention(
+        q, k, v, window=window, use_pallas=True, bq=32, bk=32) * cot)
+    gp = jax.grad(f, (0, 1, 2))(q, k, v)
+    gr = ref.ref_attention_grads(q, k, v, cot, window=window)
+    for a, b_, name in zip(gp, gr, "qkv"):
+        _close(a, b_, msg=f"d{name} sq={sq} skv={skv} window={window}")
+    reached = skv - sq - window + 1               # first key any query sees
+    np.testing.assert_array_equal(np.asarray(gp[1][:, :reached]), 0.0)
+    np.testing.assert_array_equal(np.asarray(gp[2][:, :reached]), 0.0)
+
+
+@pytest.mark.parametrize("window", [10, 24])
+def test_attention_grad_parity_dead_rows_window(window):
+    """Dead rows (Sq > Skv) under a causal window with GQA rep 8: q blocks
+    with no live kv block still write o = 0 and dq = 0."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.fold_in(KEY, window), 4)
+    q = jax.random.normal(k1, (1, 64, 8, 16), jnp.float32)
+    k = jax.random.normal(k2, (1, 32, 1, 16), jnp.float32)
+    v = jax.random.normal(k3, (1, 32, 1, 16), jnp.float32)
+    cot = jax.random.normal(k4, q.shape, jnp.float32)
+    dead = 64 - 32
+
+    def oracle(q, k, v):
+        o = ref.ref_attention(q, k, v, window=window)
+        rows = (jnp.arange(q.shape[1]) >= dead)[None, :, None, None]
+        return jnp.where(rows, o, 0.0)
+
+    f = lambda q, k, v: ops.attention(q, k, v, window=window,
+                                      use_pallas=True, bq=16, bk=16)
+    out = f(q, k, v)
+    np.testing.assert_array_equal(np.asarray(out[:, :dead]), 0.0)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle(q, k, v)),
+                               rtol=2e-3, atol=2e-3)
+    gp = jax.grad(lambda q, k, v: jnp.sum(f(q, k, v) * cot),
+                  (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda q, k, v: jnp.sum(oracle(q, k, v) * cot),
+                  (0, 1, 2))(q, k, v)
+    np.testing.assert_array_equal(np.asarray(gp[0][:, :dead]), 0.0)
+    for a, b_, name in zip(gp, gr, "qkv"):
+        _close(a, b_, msg=f"d{name} dead rows, window {window}")
 
 
 def test_attention_grad_parity_odd_dims():
