@@ -22,13 +22,9 @@ def main(argv=None):
     ap.add_argument("--skip-lm", action="store_true")
     ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--skip-ablations", action="store_true")
-    ap.add_argument("--skip-quant", action="store_true")
     args = ap.parse_args(argv)
 
     t0 = time.time()
-    if not args.skip_quant:
-        from benchmarks import quant_bench
-        quant_bench.run(quick=args.quick)
     if not args.skip_cifar:
         from benchmarks import paper_tables
         paper_tables.run_all(quick=args.quick)

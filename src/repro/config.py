@@ -125,8 +125,6 @@ class QuantConfig:
     # stochastic_rounding=False forces RTN everywhere, which ALSO disables
     # the in-kernel-PRNG quantize (controller._use_fused_prng: the fused
     # kernel is an SR kernel) — RTN leaves take the deterministic XLA path.
-    # Dense-prologue leaves stay on the kernel path either way (mode 0 is
-    # in-kernel round-half-even, bit-identical to the XLA jnp.round path).
     stochastic_rounding: bool = True
     edf_sample: int = 65536       # PushDown EDF subsample size per tensor
     loss_hist_len: int = 128      # ring buffer for strategy adaptation
@@ -151,12 +149,12 @@ class QuantConfig:
     #   * precision_switch's PushDown ladder        → edf_ladder_hists
     #   * the model forward's attention              → flash_attention
     #   * the model's DENSE LAYERS (container_dtype="int8_packed"):
-    #     models/common.dense feeds packed/prologue leaves straight to the
-    #     fxp kernels — forward streams int8 weight tiles into the MXU
+    #     models/common.dense feeds packed leaves straight to the fxp
+    #     kernels — forward streams int8 weight tiles into the MXU
     #     (dequant in-register), dx streams the SAME tiles through a
     #     transposed index map, dw = xᵀ@dy lands straight-through on the
-    #     master (kernels/ops.fxp_dense / fxp_qdense) — no dequantized
-    #     weight copy exists in HBM; tests/test_dense_path.py asserts the
+    #     master (kernels/ops.fxp_dense) — no dequantized weight copy
+    #     exists in HBM; tests/test_dense_path.py asserts the
     #     jaxpr has fwd+dx+dw per dense layer and ZERO dequantized-weight
     #     XLA matmuls.
     #     — all of it UNDER value_and_grad: every forward op carries a
@@ -185,35 +183,6 @@ class QuantConfig:
     # Noise streams are deterministic per step key but differ from the
     # jax.random stream the XLA path uses — same distribution, not same bits.
     fused_prng: bool = True
-    # dense_prologue (OPT-IN) fuses the QUANTIZE into the dense matmul
-    # PROLOGUE
-    # (kernels/fxp_matmul.fxp_qmatmul): dense-consumed leaves skip word
-    # materialization entirely — the "quantized copy" is the master plus
-    # ⟨seed, FL, mode⟩, and int8 tiles are drawn in VMEM en route to the
-    # MXU, killing the q8 HBM write+read-back round trip (ROADMAP's fused
-    # quantize-into-matmul item). Only consulted when use_pallas is set
-    # and container_dtype="int8_packed"; non-dense quantized leaves keep
-    # the materialized container either way. SR always uses the PORTABLE
-    # index-hash stream — a pure function of ⟨seed, element index⟩, so
-    # the fwd and dx recompute agree on every word even though they tile
-    # the weight differently. On CPU/interpret that makes prologue words
-    # bit-identical to sr_quantize_fused_int8 on 2-D leaves; on compiled
-    # TPU the MATERIALIZED kernel draws from the hardware PRNG instead,
-    # so the two dispatches are same-distribution, not same-bits (same
-    # caveat as fused_prng above). RTN (serving / SR off) is
-    # round-half-even, bit-identical to the XLA packed path everywhere. Explicitly-
-    # sharded dense leaves are EXCLUDED (they keep the materialized packed
-    # container): pallas_call has no SPMD partitioning rule, and a
-    # prologue dict on a mesh would gather the f32 master into every
-    # launch (controller._use_dense_prologue; ROADMAP open item). Off by
-    # default: the prologue re-reads the f32 MASTER once per M-block where
-    # the materialized path re-reads 1-byte words, so plain HBM-bytes
-    # arithmetic favors materialized words whenever the M grid has more
-    # than ~2 blocks (large-batch training); enable it for
-    # quantize-round-trip-bound regimes (the bench train_step rows
-    # measure both). Serving always materializes regardless
-    # (serve/engine.quantize_for_serving).
-    dense_prologue: bool = False
 
 
 # ---------------------------------------------------------------------------

@@ -42,7 +42,6 @@ from repro.config import QuantConfig
 from repro.core import fixed_point as fxp
 from repro.core import pushdown, pushup
 from repro.kernels import ops as kops
-from repro.kernels.sr_quantize import fold_shard_seed
 
 Array = jax.Array
 PyTree = Any
@@ -288,41 +287,6 @@ def _use_fused_prng(qcfg: QuantConfig, key, wl: Array, leaf: Array,
     return shd.shard_grid(leaf.shape, sharding.spec, sharding.mesh) is not None
 
 
-def _use_dense_prologue(qcfg: QuantConfig, path: str, fl: Array,
-                        leaf: Array, sharding=None) -> bool:
-    """True when ``leaf`` should skip word materialization entirely and be
-    quantized in the MATMUL PROLOGUE (``kernels/ops.fxp_qdense``): packed
-    mode only, behind ``use_pallas`` + ``dense_prologue``, and only for
-    leaves ``models/common.dense`` actually feeds to the kernels — a 2-D
-    weight (scalar ⟨WL,FL⟩) or a per-layer-stacked (L, K, N) weight with
-    an (L,)-vector precision, named in ``fixed_point.DENSE_PARAM_NAMES``.
-    Everything else (embed tables, conv kernels, MoE expert matrices, which
-    the grouped kernels read as words) keeps the materialized packed
-    container. Works for SR (per-leaf/-layer seeds, portable index-hash
-    stream) AND RTN (key=None /
-    stochastic_rounding off → mode 0, bit-identical to ``jnp.round``),
-    so serving takes the same path.
-
-    EXPLICITLY-SHARDED leaves are excluded: pallas_call has no SPMD
-    partitioning rule, so a prologue dict on a mesh would make GSPMD
-    gather the f32 MASTER into every dense kernel launch — 4× the wire
-    bytes of the 1-byte packed container those leaves keep instead
-    (whose q8 payload is what the mesh moves either way). A shard_map
-    wrapper for the dense matmul kernels is the open ROADMAP item."""
-    if not (qcfg.use_pallas and qcfg.dense_prologue):
-        return False
-    if not fxp.is_dense_param(path):
-        return False
-    if sharding is not None:
-        if not isinstance(sharding, NamedSharding):
-            return False
-        if any(shd.spec_dim_axes(sharding.spec, leaf.ndim)):
-            return False
-    if fl.ndim == 0:
-        return leaf.ndim == 2
-    return fl.ndim == 1 and leaf.ndim == 3 and fl.shape[0] == leaf.shape[0]
-
-
 def quantize_params(params: PyTree, state: Dict[str, Any], qcfg: QuantConfig,
                     key: Array | None = None, dtype=jnp.float32,
                     shardings: PyTree | None = None) -> PyTree:
@@ -412,23 +376,13 @@ def quantize_params_packed(params: PyTree, state: Dict[str, Any],
     (see fixed_point.PACKED_KEYS); consumers call fxp.unpack_tree AT the use
     site — inside the scanned layer body, after the per-layer gather — so
     weights cross the mesh as int8 (4× less than the f32 container).
-    Differentiate w.r.t. this tree: cotangents land on each "wref".
-
-    Dense-consumed leaves (``fixed_point.is_dense_param``) under
-    ``use_pallas`` + ``dense_prologue`` skip the word materialization
-    entirely: they come back as quantize-PROLOGUE dicts ⟨wm, seed, flq,
-    mode⟩ — the master itself plus the draw metadata — and the matmul
-    kernel quantizes tiles in-register (``kernels/ops.fxp_qdense``), so no
-    quantized weight tensor exists in HBM at all. Cotangents for those
-    land on "wm" (straight-through dw); ``strip_packed_grads`` extracts
-    both flavors."""
+    Differentiate w.r.t. this tree: cotangents land on each "wref"."""
     tensors = state["tensors"]
     flat_sh = None
     if shardings is not None:
         flat_sh = dict(
             (path_str(p), s) for p, s in
             jax.tree_util.tree_flatten_with_path(shardings)[0])
-    sr = bool(qcfg.stochastic_rounding and key is not None)
 
     def _sc_for(p, leaf, fl):
         """Dequant scale 2^-FL, shaped so the scan can slice it: per-layer
@@ -466,18 +420,6 @@ def quantize_params_packed(params: PyTree, state: Dict[str, Any],
                 "cannot be partitioned by GSPMD and would replicate every "
                 "launch. Disable quant.use_pallas for mesh runs (ROADMAP: "
                 "shard_map wrapper for the dense matmul kernels).")
-        if _use_dense_prologue(qcfg, p, fl, leaf, sh):
-            if fl.shape:          # stacked: per-layer folded seeds so layer
-                ls = jnp.arange(fl.shape[0], dtype=jnp.int32)  # l owns its
-                seed = fold_shard_seed(                        # own stream
-                    _leaf_seed(key, p) if sr else jnp.int32(0), ls)
-            else:
-                seed = _leaf_seed(key, p) if sr else jnp.int32(0)
-            wm = leaf.astype(jnp.float32)
-            if sh is not None:
-                wm = jax.lax.with_sharding_constraint(wm, sh)
-            return {"wm": wm, "seed": seed, "flq": fl,
-                    "mode": jnp.full(fl.shape, 1 if sr else 0, jnp.int32)}
         if fl.ndim == 2 and sh is None and _use_fused_prng(
                 qcfg, key, fl.reshape(-1), leaf.reshape(
                     (-1,) + leaf.shape[2:])):
@@ -523,16 +465,10 @@ def quantize_params_packed(params: PyTree, state: Dict[str, Any],
 
 def strip_packed_grads(grads: PyTree) -> PyTree:
     """Grad tree of a packed qparams tree → plain per-param grads. A
-    packed dict's cotangent lives in its "wref" (q8 carries float0); a
-    quantize-prologue dict's lives in its "wm" — the straight-through
-    dw = xᵀ@dy the dense kernels deposit directly on the master."""
-    def is_q(g):
-        return isinstance(g, dict) and frozenset(g) in (fxp.PACKED_KEYS,
-                                                        fxp.QDENSE_KEYS)
-
+    packed dict's cotangent lives in its "wref" (q8 carries float0)."""
     return jax.tree_util.tree_map(
-        lambda g: (g["wref"] if "wref" in g else g["wm"]) if is_q(g) else g,
-        grads, is_leaf=is_q)
+        lambda g: g["wref"] if fxp.is_packed(g) else g, grads,
+        is_leaf=fxp.is_packed)
 
 
 def clamp_adapt_state(state: Dict[str, Any], max_wl) -> Dict[str, Any]:

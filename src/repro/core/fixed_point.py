@@ -121,15 +121,6 @@ def uniform_noise_like(key: Array, x: Array) -> Array:
 
 PACKED_KEYS = frozenset(("q8", "sc", "wref"))
 
-# Quantize-PROLOGUE leaf format: the "quantized copy" of a dense-consumed
-# weight is just the MASTER + ⟨seed, FL, rounding mode⟩ — the int8 words are
-# drawn in-register inside the matmul prologue (kernels/fxp_matmul.fxp_qmatmul)
-# and never exist in HBM. "wm" is the f32 master itself (no copy), "seed"/
-# "flq"/"mode" are int32 (per-layer (L,)-vectors on stacked leaves so the
-# scan slices them alongside wm). Gradients land on "wm" directly (straight-
-# through dw = xᵀ@dy); controller.strip_packed_grads extracts them.
-QDENSE_KEYS = frozenset(("wm", "seed", "flq", "mode"))
-
 # Param-tree leaf names consumed by models/common.dense (2-D x@W matmuls).
 # Only these are eligible for the kernel dense path. MoE expert matrices
 # (EXPERT_PARAM_NAMES) keep the materialized packed container and, under
@@ -159,10 +150,6 @@ def is_packed(leaf) -> bool:
     return isinstance(leaf, dict) and frozenset(leaf) == PACKED_KEYS
 
 
-def is_qdense(leaf) -> bool:
-    return isinstance(leaf, dict) and frozenset(leaf) == QDENSE_KEYS
-
-
 def is_dense_param(path: str) -> bool:
     """True when the (slash-joined) param path names a dense-layer weight
     — the leaves ``models/common.dense`` knows how to feed to the Pallas
@@ -190,34 +177,11 @@ def _dequant_bwd(sc, g):
 dequant_packed.defvjp(_dequant_fwd, _dequant_bwd)
 
 
-def qdense_view(wm: Array, seed: Array, flq: Array, mode: Array) -> Array:
-    """Materialize (in XLA) the value view of a quantize-prologue leaf:
-    the dequantized ⟨8,FL⟩ words the matmul prologue draws in-register,
-    regenerated from the bit-pinned portable stream (kernels/ref.py). Used
-    for the regularizer terms — elementwise + scalar reductions, so XLA
-    fuses it into the penalty reduction and no param-sized copy lands in
-    HBM. Straight-through: the cotangent passes to ``wm`` unchanged."""
-    from repro.kernels import ref as _ref
-
-    def one(w, s, f, m):
-        words = _ref.ref_qdense_words(w, s, f, m).astype(jnp.float32)
-        return words * jnp.ldexp(jnp.float32(1.0), -jnp.asarray(f, jnp.int32))
-
-    view = (jax.vmap(one)(wm, seed, flq, mode) if jnp.ndim(flq)
-            else one(wm, seed, flq, mode))
-    view = view.astype(wm.dtype)
-    return wm + jax.lax.stop_gradient(view - wm)
-
-
-def _is_quantized_dict(leaf) -> bool:
-    return is_packed(leaf) or is_qdense(leaf)
-
-
 def unpack_tree(tree, keep_dense: bool = False):
-    """Dequantize every packed / prologue leaf in a (sub)tree; plain leaves
-    pass. ``keep_dense=True`` leaves dicts whose path names a dense-layer
-    weight (``is_dense_param``) or an expert matrix (``is_expert_param``)
-    INTACT — the kernels consume them directly (``models/common.dense``,
+    """Dequantize every packed leaf in a (sub)tree; plain leaves pass.
+    ``keep_dense=True`` leaves dicts whose path names a dense-layer weight
+    (``is_dense_param``) or an expert matrix (``is_expert_param``) INTACT
+    — the kernels consume them directly (``models/common.dense``,
     ``models/moe.py``), so they must survive the use-site unpack that
     every other quantized leaf still gets.
 
@@ -230,15 +194,12 @@ def unpack_tree(tree, keep_dense: bool = False):
     specs = _sh.flag("#packed_slice_specs") or {}
 
     def visit(path, leaf):
-        if not _is_quantized_dict(leaf):
+        if not is_packed(leaf):
             return leaf
         key = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                        for k in path)
         if keep_dense and (is_dense_param(key) or is_expert_param(key)):
             return leaf
-        if is_qdense(leaf):
-            return qdense_view(leaf["wm"], leaf["seed"], leaf["flq"],
-                               leaf["mode"])
         q8 = leaf["q8"]
         if specs:
             for suffix, spec in specs.items():
@@ -249,7 +210,7 @@ def unpack_tree(tree, keep_dense: bool = False):
         return dequant_packed(q8, leaf["sc"], leaf["wref"])
 
     return jax.tree_util.tree_map_with_path(visit, tree,
-                                            is_leaf=_is_quantized_dict)
+                                            is_leaf=is_packed)
 
 
 def sparsity(w: Array, axes=None, eps: float = 0.0) -> Array:

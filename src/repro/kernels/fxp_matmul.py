@@ -47,8 +47,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import sr_quantize as _sq
-
 Array = jax.Array
 
 
@@ -88,9 +86,7 @@ def _clamp_block(b: int, d: int) -> int:
 _DENSE_KINDS = {
     "fwd": ("M", "N", "K"),     # fxp_matmul: x @ words
     "int8": ("M", "N", "K"),    # int8_matmul: xq @ wq
-    "qfwd": ("M", "N", "K"),    # fxp_qmatmul: x @ Q(master)
     "dx": ("M", "K", "N"),      # matmul_dx: dy @ wordsᵀ
-    "qdx": ("M", "K", "N"),     # matmul_qdx: dy @ Q(master)ᵀ
     "dw": ("K", "N", "M"),      # matmul_dw: xᵀ @ dy
 }
 _DENSE_CAP = 1024
@@ -98,7 +94,6 @@ _RIDGE_BF16, _RIDGE_INT8 = 240, 480     # FLOP/byte on v5e
 _LANE = 128
 _VMEM_BUDGET = 48 * 2**20               # reckoned bytes a rule block may take
 _VMEM_SCOPED = 16 * 2**20               # Mosaic's default scoped VMEM (v5e)
-_QUANT_TEMPS = 8                        # f32 (r, q) tiles of _quantize_w_tile
 
 
 def _split_dim(d: int, cap: int) -> int:
@@ -120,16 +115,13 @@ def _dense_vmem(kind: str, p: int, q: int, r: int, a_bytes: int,
     """Reckoned VMEM of one grid step: the double-buffered A, B and output
     tiles, the 4-byte accumulator and the dot's 4-byte result, and the
     kind's in-register temporaries (the words cast to A's dtype; dw's A
-    tile transposed for the MXU; the prologue's f32 A tile and its
-    quantize temporaries)."""
+    tile transposed for the MXU)."""
     need = 2 * (p * r * a_bytes + r * q * b_bytes) + 2 * p * q * out_bytes
     need += 2 * p * q * 4
     if kind in ("fwd", "dx"):
         need += r * q * a_bytes
     elif kind == "dw":
         need += p * r * a_bytes
-    elif kind in ("qfwd", "qdx"):
-        need += p * r * 4 + _QUANT_TEMPS * r * q * 4
     return need
 
 
@@ -444,172 +436,6 @@ def matmul_dw(x: Array, dy: Array, *, bm: int | None = None,
 
 
 # ---------------------------------------------------------------------------
-# Quantize-prologue variant: the matmul consumes the float MASTER weight
-# plus ⟨FL, seed, mode⟩ and quantizes each tile in-register on the way into
-# the MXU — the int8 words exist only in VMEM, never in HBM (closes the
-# "fused quantize-into-matmul" ROADMAP item: no q8 write+read-back round
-# trip on freshly re-quantized layers). The noise is the PORTABLE
-# counter-hash stream over the weight element's flat index (k·N + n), NOT
-# the hardware PRNG: the words must be a pure function of ⟨seed, element⟩
-# so the forward launch and the dx recompute — which tile the same weight
-# differently — draw bit-identical words. For an unstacked (K, N) leaf
-# this is the exact stream of ``sr_quantize_fused_int8``'s PORTABLE mode,
-# so prologue and materialized words match bit-for-bit under interpret /
-# CPU CI (tests/test_dense_path.py pins this); on compiled TPU the
-# materialized kernel uses the hardware PRNG, so there the two dispatches
-# agree in distribution, not bits.
-#
-# ``mode`` selects rounding at trace-free runtime: 1 = stochastic (SR),
-# 0 = round-to-nearest-even (matches the XLA ``jnp.round`` packed path
-# exactly, ties included — serving and SR-off training stay bit-compatible
-# across dispatches).
-
-
-def _quantize_w_tile(w: Array, fl, seed, mode, k0, n0, n_dim: int) -> Array:
-    """In-register ⟨8,FL⟩ quantize of one (bk, bn) master-weight tile to
-    int8-range fixed-point words (f32 values, int8 range by clip)."""
-    scale = _sq._pow2i(fl)
-    s = w * scale
-    r = jax.lax.broadcasted_iota(jnp.uint32, w.shape, 0)
-    c = jax.lax.broadcasted_iota(jnp.uint32, w.shape, 1)
-    idx = (k0.astype(jnp.uint32) + r) * jnp.uint32(n_dim) \
-        + n0.astype(jnp.uint32) + c
-    u = _sq.uniform_from_index(seed, idx)
-    f = jnp.floor(s)
-    q_sr = f + (u < (s - f)).astype(jnp.float32)
-    q = jnp.where(mode == 1, q_sr, jnp.round(s))
-    return jnp.clip(q, -128.0, 127.0)
-
-
-def _fxp_qmatmul_kernel(ctl_ref, x_ref, w_ref, o_ref, acc_ref, *, nk: int,
-                        dims: tuple):
-    M, K, N = dims
-    i, j, ik = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    fl, seed, mode = ctl_ref[0, 0], ctl_ref[0, 1], ctl_ref[0, 2]
-    x = _mask_tail(x_ref[...].astype(jnp.float32), 1, ik, K)
-    w = w_ref[...].astype(jnp.float32)
-    bk, bn = w.shape
-    q = _quantize_w_tile(w, fl, seed, mode, k0=ik * bk, n0=j * bn, n_dim=N)
-    # K is contracted: garbage padding quantizes to garbage words (NaN
-    # survives the clip), so the K tails of BOTH operands go to exact zero.
-    q = _mask_tail(q, 0, ik, K)
-    acc_ref[...] += jax.lax.dot_general(
-        x, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(ik == nk - 1)
-    def _done():
-        out = acc_ref[...] * _sq._pow2i(-fl)
-        out = _mask_tail(_mask_tail(out, 0, i, M), 1, j, N)
-        o_ref[...] = out.astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
-                                             "out_dtype"))
-def fxp_qmatmul(x: Array, w: Array, seed: Array, fl: Array, mode: Array, *,
-                bm: int | None = None, bn: int | None = None,
-                bk: int | None = None, out_dtype=None,
-                interpret: bool = False) -> Array:
-    """y = x @ (Q⟨8,fl⟩(w) · 2^-fl), quantizing ``w`` in the matmul
-    prologue. x: (M,K) float; w: (K,N) float MASTER; seed/fl/mode: int32
-    scalars (mode 1 = SR via the portable index-hash stream, 0 = RTN).
-    Any ⟨M,K,N⟩ is accepted — partial boundary blocks are tail-masked."""
-    M, K = x.shape
-    K2, N = w.shape
-    assert K == K2, (x.shape, w.shape)
-    out_dtype = out_dtype or x.dtype
-    bm, bn, bk, params = _pick_blocks("qfwd", M, K, N, bm, bn, bk, x.dtype,
-                                      w.dtype, out_dtype)
-    grid = (pl.cdiv(M, bm), pl.cdiv(N, bn), pl.cdiv(K, bk))
-    kernel = functools.partial(_fxp_qmatmul_kernel, nk=grid[2],
-                               dims=(M, K, N))
-    ctl = jnp.stack([jnp.asarray(fl), jnp.asarray(seed),
-                     jnp.asarray(mode)]).astype(jnp.int32).reshape(1, 3)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-        compiler_params=params,
-    )(ctl, x, w)
-
-
-def _matmul_qdx_kernel(ctl_ref, dy_ref, w_ref, dx_ref, acc_ref, *, nn: int,
-                       dims: tuple):
-    """dx = dy @ Q(w)ᵀ·2^-fl — the prologue's dx recompute: the SAME master
-    tiles the forward read (transposed index map), re-quantized in-register
-    with the SAME index-hash words, so fwd and bwd agree on every bit of
-    the weight draw without any HBM word copy existing."""
-    M, K, N = dims
-    i, j, n = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(n == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    fl, seed, mode = ctl_ref[0, 0], ctl_ref[0, 1], ctl_ref[0, 2]
-    dy = _mask_tail(dy_ref[...].astype(jnp.float32), 1, n, N)
-    w = w_ref[...].astype(jnp.float32)
-    bk, bn = w.shape
-    q = _quantize_w_tile(w, fl, seed, mode, k0=j * bk, n0=n * bn, n_dim=N)
-    # N is the contracted dim here — zero both N tails before the MXU.
-    q = _mask_tail(q, 1, n, N)
-    acc_ref[...] += jax.lax.dot_general(
-        dy, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(n == nn - 1)
-    def _done():
-        out = acc_ref[...] * _sq._pow2i(-fl)
-        out = _mask_tail(_mask_tail(out, 0, i, M), 1, j, K)
-        dx_ref[...] = out.astype(dx_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
-                                             "out_dtype"))
-def matmul_qdx(dy: Array, w: Array, seed: Array, fl: Array, mode: Array, *,
-               bm: int | None = None, bn: int | None = None,
-               bk: int | None = None, out_dtype=None,
-               interpret: bool = False) -> Array:
-    """dx = dy @ (Q⟨8,fl⟩(w)·2^-fl)ᵀ.  dy: (M,N); w: (K,N) float master."""
-    M, N = dy.shape
-    K, N2 = w.shape
-    assert N == N2, (dy.shape, w.shape)
-    out_dtype = out_dtype or dy.dtype
-    bm, bn, bk, params = _pick_blocks("qdx", M, K, N, bm, bn, bk, dy.dtype,
-                                      w.dtype, out_dtype)
-    grid = (pl.cdiv(M, bm), pl.cdiv(K, bk), pl.cdiv(N, bn))
-    kernel = functools.partial(_matmul_qdx_kernel, nn=grid[2],
-                               dims=(M, K, N))
-    ctl = jnp.stack([jnp.asarray(fl), jnp.asarray(seed),
-                     jnp.asarray(mode)]).astype(jnp.int32).reshape(1, 3)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((bm, bn), lambda i, j, n: (i, n)),
-            pl.BlockSpec((bk, bn), lambda i, j, n: (j, n)),   # transposed map
-        ],
-        out_specs=pl.BlockSpec((bm, bk), lambda i, j, n: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, K), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
-        interpret=interpret,
-        compiler_params=params,
-    )(ctl, dy, w)
-
-
-# ---------------------------------------------------------------------------
 # custom_vjp rules
 
 
@@ -687,12 +513,12 @@ def int8_matmul_vjp(xq: Array, wq: Array, sx: Array, sw: Array, *,
 
 
 # ---------------------------------------------------------------------------
-# Dense-layer rules: the model's TRAINING matmul. Unlike ``fxp_matmul_vjp``
-# (whose weight cotangent is only contracted into dscale), these carry the
+# Dense-layer rule: the model's TRAINING matmul. Unlike ``fxp_matmul_vjp``
+# (whose weight cotangent is only contracted into dscale), it carries the
 # straight-through gradient of paper alg. 1: the full dw = xᵀ@dy lands on
-# the MASTER copy (wref for materialized words, wm for the prologue), so
-# the optimizer step is exactly the one the XLA dequant-then-dot path
-# produces — while the forward/dx stream int8 tiles through the MXU.
+# the MASTER copy through the ``wref`` receiver, so the optimizer step is
+# exactly the one the XLA dequant-then-dot path produces — while the
+# forward/dx stream int8 tiles through the MXU.
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -733,42 +559,3 @@ def fxp_dense_vjp(x: Array, wq: Array, scale: Array, wref: Array, *,
     per-layer 2^-FL); ``wref`` is never read (its cotangent is the output)."""
     return _fxp_dense_diff((bm, bn, bk, out_dtype, interpret,
                             jnp.dtype(wref.dtype)), x, wq, scale, wref)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _fxp_qdense_diff(cfg, x, w, seed, fl, mode):
-    bm, bn, bk, out_dtype, interpret = cfg
-    return fxp_qmatmul(x, w, seed, fl, mode, bm=bm, bn=bn, bk=bk,
-                       out_dtype=out_dtype, interpret=interpret)
-
-
-def _fxp_qdense_diff_fwd(cfg, x, w, seed, fl, mode):
-    return _fxp_qdense_diff(cfg, x, w, seed, fl, mode), (x, w, seed, fl, mode)
-
-
-def _fxp_qdense_diff_bwd(cfg, res, dy):
-    bm, bn, bk, _, interpret = cfg
-    x, w, seed, fl, mode = res
-    dx = matmul_qdx(dy, w, seed, fl, mode, bm=bm, bn=bn, bk=bk,
-                    out_dtype=x.dtype, interpret=interpret)
-    dw = matmul_dw(x, dy, bm=bm, bn=bn, bk=bk, interpret=interpret)
-    return (dx, dw.astype(w.dtype), float0_like(seed), float0_like(fl),
-            float0_like(mode))
-
-
-_fxp_qdense_diff.defvjp(_fxp_qdense_diff_fwd, _fxp_qdense_diff_bwd)
-
-
-def fxp_qdense_vjp(x: Array, w: Array, seed: Array, fl: Array, mode: Array,
-                   *, bm: int | None = None, bn: int | None = None,
-                   bk: int | None = None, out_dtype=None,
-                   interpret: bool = False) -> Array:
-    """Differentiable quantize-prologue dense layer: forward is
-    :func:`fxp_qmatmul` (master in, words only ever in VMEM), dx is
-    :func:`matmul_qdx` (same index-hash words, recomputed in-register), and
-    the straight-through dw = xᵀ@dy lands directly on ``w`` — which IS the
-    master copy, so no quantized weight tensor exists in HBM at all."""
-    return _fxp_qdense_diff(
-        (bm, bn, bk, out_dtype, interpret), x, w,
-        jnp.asarray(seed, jnp.int32), jnp.asarray(fl, jnp.int32),
-        jnp.asarray(mode, jnp.int32))

@@ -15,7 +15,6 @@ from repro.kernels import edf_ladder as _el
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fxp_gmm as _fg
 from repro.kernels import fxp_matmul as _fm
-from repro.kernels import kl_hist as _kh
 from repro.kernels import ref
 from repro.kernels import sr_quantize as _sq
 
@@ -264,33 +263,6 @@ def ragged_dot(x: Array, w: Array, sizes: Array) -> Array:
         return dot(x.astype(jnp.float32), w.astype(jnp.float32))
 
     return jax.lax.platform_dependent(x, w, cpu=upcast, default=dot)
-
-
-def fxp_qdense(x: Array, w: Array, seed: Array, fl: Array, mode: Array, *,
-               use_pallas: bool = False, out_dtype=None) -> Array:
-    """Quantize-PROLOGUE dense layer: consumes the float MASTER weight +
-    ⟨FL, seed, mode⟩ and quantizes tiles in-register en route to the MXU —
-    the int8 words only ever exist in VMEM (no q8 HBM round trip on
-    freshly re-quantized layers). mode: 1 = SR (portable index-hash
-    stream, bit-identical to ``sr_quantize_fused_int8`` for a 2-D leaf),
-    0 = RTN (round-half-even, bit-identical to the XLA packed path).
-    Straight-through: dw = xᵀ@dy lands directly on ``w`` (the master)."""
-    seed = jnp.asarray(seed, jnp.int32)
-    fl = jnp.asarray(fl, jnp.int32)
-    mode = jnp.asarray(mode, jnp.int32)
-    if use_pallas:
-        return _fm.fxp_qdense_vjp(x, w, seed, fl, mode,
-                                  out_dtype=out_dtype,
-                                  interpret=not _on_tpu())
-    return ref.ref_fxp_qdense(x, w, seed, fl, mode).astype(out_dtype
-                                                           or x.dtype)
-
-
-def kl_hist(w: Array, q: Array, num_bins: int = 256, *,
-            use_pallas: bool = False) -> Array:
-    if use_pallas:
-        return _kh.kl_hist(w, q, num_bins=num_bins, interpret=not _on_tpu())
-    return ref.ref_kl_hist(w, q, num_bins)
 
 
 def attention(q: Array, k: Array, v: Array, *, causal: bool = True,

@@ -110,37 +110,6 @@ def ref_sr_quantize_fused_int8_words(x: Array, seed, fl) -> Array:
     return jnp.clip(q, -128.0, 127.0).astype(jnp.int8)
 
 
-def ref_qdense_words(w: Array, seed, fl, mode=1) -> Array:
-    """Bit-exact oracle of the quantize-prologue word draw
-    (``fxp_matmul._quantize_w_tile``): element (k, n) of a (K, N) master
-    hashes its flat index k·N + n, which for a 2-D leaf is EXACTLY the
-    ``sr_quantize_fused_int8`` PORTABLE stream — prologue and materialized
-    words agree bit-for-bit wherever both use it (interpret mode / CPU
-    CI; on compiled TPU the materialized kernel draws from the hardware
-    PRNG instead, so there the dispatches agree in distribution only).
-    ``mode`` 1 = SR, 0 = RTN (round-half-even, matching ``jnp.round``
-    on every backend)."""
-    xf = w.astype(jnp.float32) * _pow2(fl)
-    u = ref_fused_noise(seed, w.size).reshape(w.shape)
-    f = jnp.floor(xf)
-    q_sr = f + (u < (xf - f)).astype(jnp.float32)
-    q = jnp.where(jnp.asarray(mode) == 1, q_sr, jnp.round(xf))
-    return jnp.clip(q, -128.0, 127.0).astype(jnp.int8)
-
-
-def ref_fxp_qdense(x: Array, w: Array, seed, fl, mode=1) -> Array:
-    """Forward oracle of ``fxp_qmatmul``: x @ (words · 2^-fl) with the
-    straight-through view (differentiating this gives dx through the
-    dequantized words and dw = xᵀ@dy onto the master — the same cotangents
-    the Pallas VJP produces)."""
-    words = jax.lax.stop_gradient(
-        ref_qdense_words(w, seed, fl, mode).astype(jnp.float32))
-    wv = w + jax.lax.stop_gradient(words * _pow2(-fl) - w)
-    acc = jnp.dot(x.astype(jnp.float32), wv.astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
-    return acc.astype(x.dtype)
-
-
 def _stacked_offsets(x: Array):
     n = x[0].size
     rows = -(-n // FUSED_LANES)
@@ -320,21 +289,6 @@ def ref_attention_grads(q: Array, k: Array, v: Array, dy: Array, **kwargs):
     _, vjp = jax.vjp(lambda a, b, c: ref_attention(a, b, c, **kwargs),
                      q, k, v)
     return vjp(dy)
-
-
-def ref_kl_hist(w: Array, q: Array, num_bins: int) -> Array:
-    """Fused double histogram: counts (2, num_bins) of w and q over w's range."""
-    wf = w.astype(jnp.float32).reshape(-1)
-    qf = q.astype(jnp.float32).reshape(-1)
-    lo, hi = jnp.min(wf), jnp.max(wf)
-    span = jnp.maximum(hi - lo, 1e-12)
-
-    def hist(x):
-        idx = jnp.clip(jnp.floor((x - lo) / span * num_bins),
-                       0, num_bins - 1).astype(jnp.int32)
-        return jnp.zeros((num_bins,), jnp.float32).at[idx].add(1.0)
-
-    return jnp.stack([hist(wf), hist(qf)])
 
 
 def ref_attention(q: Array, k: Array, v: Array, *, causal: bool = True,

@@ -119,8 +119,8 @@ def uniform_from_index(seed: Array, idx: Array) -> Array:
     portable stream (``ref.ref_fused_noise`` regenerates it; the golden
     file trips on drift) — every kernel that draws noise for element
     ``idx`` of a tensor must come through here so streams agree across
-    kernels that tile the same tensor differently (e.g. the quantize
-    prologue of ``fxp_matmul.fxp_qmatmul`` vs its dx recompute)."""
+    kernels that tile the same tensor differently (e.g. the flat and the
+    stacked fused quantize, at any block rows)."""
     h = idx.astype(jnp.uint32) + seed.astype(jnp.uint32) * jnp.uint32(0x9E3779B9)
     h ^= h >> 16
     h = h * jnp.uint32(0x7FEB352D)

@@ -52,18 +52,13 @@ def dense(x: Array, w, *, out_logical: str | None = None,
           use_pallas: bool = False) -> Array:
     """x @ w with f32 accumulation; annotates the contraction output.
 
-    ``w`` is either a plain weight array or a QUANTIZED-LEAF dict the
-    controller emitted (container_dtype="int8_packed"):
-
-    * packed ⟨q8, sc, wref⟩ — materialized int8 words. Under
-      ``use_pallas`` they stream straight into the fxp Pallas kernels
-      (``kernels/ops.fxp_dense``: fwd + dx on int8 tiles, dequant
-      in-register, straight-through dw onto wref) — the weights are never
-      dequantized into HBM. Without it, the XLA dequant-then-dot path.
-    * prologue ⟨wm, seed, flq, mode⟩ — no words at all: the kernel
-      quantizes master tiles in VMEM en route to the MXU
-      (``kernels/ops.fxp_qdense``). Pallas-only by construction (the
-      controller emits it only under use_pallas + dense_prologue).
+    ``w`` is either a plain weight array or a packed ⟨q8, sc, wref⟩ dict
+    of materialized int8 words the controller emitted
+    (container_dtype="int8_packed"). Under ``use_pallas`` the words
+    stream straight into the fxp Pallas kernels (``kernels/ops.fxp_dense``:
+    fwd + dx on int8 tiles, dequant in-register, straight-through dw onto
+    wref) — the weights are never dequantized into HBM. Without it, the
+    XLA dequant-then-dot path.
 
     With the '#tp_reduce_bf16' rules flag, the plain dot's output dtype is
     bf16: the MXU still accumulates in f32 internally, but row-parallel
@@ -90,19 +85,13 @@ def dense(x: Array, w, *, out_logical: str | None = None,
 
 
 def _dense_quantized(x: Array, w: dict, use_pallas: bool) -> Array:
-    """Dense over a quantized-leaf dict; x may be (..., K) — the kernels
-    take 2-D, so leading dims are flattened into M."""
+    """Dense over a packed dict; x may be (..., K) — the kernels take 2-D,
+    so leading dims are flattened into M."""
     from repro.kernels import ops  # local: models stay importable sans ops
 
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    if fxp.is_qdense(w):
-        # scan-sliced per-layer metadata arrives as size-1 arrays
-        seed, flq, mode = (jnp.reshape(w[k], ()) for k in
-                          ("seed", "flq", "mode"))
-        y2 = ops.fxp_qdense(x2, w["wm"], seed, flq, mode,
-                            use_pallas=use_pallas, out_dtype=x.dtype)
-    elif fxp.is_packed(w):
+    if fxp.is_packed(w):
         if use_pallas:
             y2 = ops.fxp_dense(x2, w["q8"], jnp.reshape(w["sc"], ()),
                                w["wref"], use_pallas=True, out_dtype=x.dtype)

@@ -39,13 +39,12 @@ from repro.models import attention, common, mlp, moe, ssm
 
 Array = jax.Array
 
-# Quantized leaves (fxp.PACKED_KEYS / QDENSE_KEYS dicts) are dequantized at
-# the use site: INSIDE the scan body for per-layer weights (so the FSDP
-# gather moves int8, not bf16/f32) and at entry for embed/head. Under
-# use_pallas, DENSE-consumed leaves (fixed_point.DENSE_PARAM_NAMES) are NOT
-# dequantized at all — they ride through intact and common.dense feeds them
-# straight to the fxp Pallas kernels (int8 tiles into the MXU, dequant
-# in-register; quantize-prologue leaves never materialize words anywhere).
+# Quantized leaves (fxp.PACKED_KEYS dicts) are dequantized at the use site:
+# INSIDE the scan body for per-layer weights (so the FSDP gather moves int8,
+# not bf16/f32) and at entry for embed/head. Under use_pallas,
+# DENSE-consumed leaves (fixed_point.DENSE_PARAM_NAMES) are NOT dequantized
+# at all — they ride through intact and common.dense feeds them straight to
+# the fxp Pallas kernels (int8 tiles into the MXU, dequant in-register).
 _unpack = fxp.unpack_tree
 
 

@@ -39,19 +39,13 @@ def quantize_for_serving(params, adapt_state, qcfg, max_wl=None):
     packed tree format the train step uses — dense layers feed int8 words
     straight to the fxp Pallas kernels via models/common.dense, so train
     and serve share one code path and one word draw (RTN is bit-identical
-    across dispatches). The quantize-PROLOGUE format is deliberately
-    disabled here regardless of ``quant.dense_prologue``: weights are
-    static at serve time, so re-drawing words in every matmul prologue
-    would hold the f32 master (4× the weight bytes) and re-quantize per
-    decode step for zero benefit — serving always materializes the words
-    once, at load."""
+    across dispatches). Serving always materializes the words once, at
+    load."""
     if not adapt_state or not adapt_state.get("tensors"):
         return params
     if max_wl is not None:
         adapt_state = controller.clamp_adapt_state(adapt_state, max_wl)
     if qcfg.container_dtype == "int8_packed":
-        import dataclasses
-        qcfg = dataclasses.replace(qcfg, dense_prologue=False)
         return controller.quantize_params_packed(params, adapt_state, qcfg,
                                                  key=None)
     return controller.quantize_params(params, adapt_state, qcfg, key=None)

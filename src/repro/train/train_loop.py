@@ -89,7 +89,7 @@ def _task_loss(cfg: Config, qparams, stats, batch, act_wl=None,
     # a custom VJP whose backward passes are themselves Pallas kernels, so
     # quant.use_pallas covers the differentiated train step end to end:
     # flash attention (_flash_dq/_dkv_kernel) AND the dense layers — with
-    # container_dtype="int8_packed" the packed/prologue leaves survive to
+    # container_dtype="int8_packed" the packed leaves survive to
     # models/common.dense, which streams int8 weight tiles into the fxp
     # matmul kernels (dx via the same tiles transposed, straight-through
     # dw = xᵀ@dy onto the master; tests/test_dense_path.py pins fwd+dx+dw

@@ -7,9 +7,9 @@ of the AdaPT train step with ``interpret=False`` for one chip of a described
 ``v5e:2x2`` topology and compile it with the installed TPU compiler — no chip
 is needed, and nothing runs. Widths are SmolLM-360M's (d=960, ff=2560, 15/5
 heads of 64, vocab 49152) at seq 2048 and global batch 8, and the dense
-products of the Granite-8B cut at the same rows, each with the blocks and
-VMEM limit the shape rule picks; decode rows with a prime K compile the
-tail-masked boundary blocks.
+products of the Granite-8B and Mellum2 cuts at the same rows, each with the
+blocks and VMEM limit the shape rule picks; decode rows with a prime K compile
+the tail-masked boundary blocks.
 
 The topology is described inside a module fixture: only the worker that runs
 this file loads the TPU compiler library.
@@ -42,6 +42,10 @@ DENSE_SHAPES = [(D, D), (D, HKV * DH), (D, FF), (FF, D), (D, VOCAB)]
 G_D, G_KV, G_FF, G_VOCAB = 4096, 8 * 128, 14336, 12288
 GRANITE_DENSE_SHAPES = [(G_D, G_D), (G_D, G_KV), (G_D, G_FF), (G_FF, G_D),
                         (G_D, G_VOCAB)]
+# Mellum2's dense products (d 2304, 32/4 heads of 128, an eighth of its
+# vocabulary): q, k/v, o and head, at the same M = 16,384 rows (seq 8192,
+# global batch 2).
+MEL_DENSE_SHAPES = [(2304, 4096), (2304, 512), (4096, 2304), (2304, 12288)]
 # Mellum2's experts (d 2304, expert width 896), 32 held of 64, top-8, at
 # seq 8192 and global batch 2: the grouped kernels over the buffer of
 # 131,072 assignment rows laid out at the row tile the rule picks.
@@ -126,7 +130,8 @@ def test_edf_ladder_hists(one_chip, per_layer):
              _spec(one_chip, lead, jnp.int32))
 
 
-@pytest.mark.parametrize("kn", DENSE_SHAPES + GRANITE_DENSE_SHAPES)
+@pytest.mark.parametrize("kn", DENSE_SHAPES + GRANITE_DENSE_SHAPES
+                         + MEL_DENSE_SHAPES)
 def test_fxp_dense_vjp_grad(one_chip, kn):
     K, N = kn
 
@@ -139,20 +144,6 @@ def test_fxp_dense_vjp_grad(one_chip, kn):
              _spec(one_chip, (K, N), jnp.int8),
              _spec(one_chip, (), jnp.float32),
              _spec(one_chip, (K, N), jnp.bfloat16))
-
-
-@pytest.mark.parametrize("kn", DENSE_SHAPES)
-def test_fxp_qdense_vjp_grad(one_chip, kn):
-    K, N = kn
-
-    def loss(x, w, seed, fl, mode):
-        y = fm.fxp_qdense_vjp(x, w, seed, fl, mode, interpret=False)
-        return jnp.sum(y.astype(jnp.float32))
-
-    i32 = _spec(one_chip, (), jnp.int32)
-    _compile(jax.grad(loss, argnums=(0, 1)),
-             _spec(one_chip, (M, K), jnp.bfloat16),
-             _spec(one_chip, (K, N), jnp.float32), i32, i32, i32)
 
 
 def test_flash_attention_vjp_grad(one_chip):

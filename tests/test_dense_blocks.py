@@ -2,8 +2,9 @@
 
 Every dense entry point that is given no block takes the rule's, which is a
 function of the kernel kind, (M, K, N) and the operand dtypes only. At every
-dense shape of the two benchmark cells (SmolLM-360M and the Granite-8B cut,
-M = 16,384 rows) and at serving-decode rows (M = 1, 4), the rule's blocks:
+dense shape of the three benchmark cells (SmolLM-360M, the Granite-8B cut and
+the Mellum2 cut, M = 16,384 rows), at one 2,048-token sequence (M = 2,048,
+two row blocks) and at serving-decode rows (M = 1, 4), the rule's blocks:
 
   * are the whole dim or a multiple of 128 (the (8, 128) tiling, and the 32
     sublanes of an int8 operand);
@@ -31,7 +32,9 @@ BF16_RIDGE = 240            # 197 TFLOP/s ÷ 819 GB/s, v5e
 SMOLLM_KN = [(960, 960), (960, 320), (960, 2560), (2560, 960), (960, 49152)]
 GRANITE_KN = [(4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096),
               (4096, 12288)]
-ROWS = [16384, 1, 4]
+# Mellum2's dense products: q, k/v, o, head (its FFN runs on the experts).
+MELLUM_KN = [(2304, 4096), (2304, 512), (4096, 2304), (2304, 12288)]
+ROWS = [16384, 2048, 1, 4]
 
 BF, I8, F32 = jnp.bfloat16, jnp.int8, jnp.float32
 # kind → (A dtype, B dtype, out dtype) as the model's training step and
@@ -41,14 +44,12 @@ KINDS = {
     "dx": (BF, I8, BF),
     "dw": (BF, BF, F32),
     "int8": (I8, I8, F32),
-    "qfwd": (BF, F32, BF),
-    "qdx": (BF, F32, BF),
 }
 
 
 @pytest.mark.parametrize("kind", list(KINDS))
 @pytest.mark.parametrize("m", ROWS)
-@pytest.mark.parametrize("kn", SMOLLM_KN + GRANITE_KN)
+@pytest.mark.parametrize("kn", SMOLLM_KN + GRANITE_KN + MELLUM_KN)
 def test_rule_blocks(kind, m, kn):
     K, N = kn
     a, b, out = KINDS[kind]
@@ -69,12 +70,12 @@ def test_rule_blocks(kind, m, kn):
 
 
 def test_rule_depends_on_dtypes():
-    """At SmolLM's k/v width (N = 320, one whole block) the int8 words
-    clear the ridge at 1024 rows; the prologue's f32 master needs twice as
-    many rows per block to amortize its re-reads."""
+    """At SmolLM's k/v width (N = 320, one whole block) int8 words clear
+    the ridge at 1024 rows; f32 words need twice as many rows per block to
+    amortize their re-reads."""
     words = fm._dense_blocks("fwd", 16384, 960, 320, BF, I8, BF)
-    master = fm._dense_blocks("qfwd", 16384, 960, 320, BF, F32, BF)
-    assert (words["M"], master["M"]) == (1024, 2048)
+    wide = fm._dense_blocks("fwd", 16384, 960, 320, BF, F32, BF)
+    assert (words["M"], wide["M"]) == (1024, 2048)
 
 
 def test_requested_blocks_override_rule():

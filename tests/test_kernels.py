@@ -85,23 +85,6 @@ def test_int8_matmul_exact_integer_accumulation():
 
 
 # ---------------------------------------------------------------------------
-# kl_hist
-
-
-@pytest.mark.parametrize("n", [100, 4096, 70000])
-@pytest.mark.parametrize("bins", [50, 150, 256])
-def test_kl_hist_matches_ref(n, bins):
-    k1, _ = jax.random.split(KEY)
-    w = jax.random.normal(k1, (n,))
-    q = jnp.round(w * 8) / 8
-    got = ops.kl_hist(w, q, bins, use_pallas=True)
-    want = ref.ref_kl_hist(w, q, bins)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3)
-    assert abs(float(got[0].sum()) - n) < 1e-3
-    assert abs(float(got[1].sum()) - n) < 1e-3
-
-
-# ---------------------------------------------------------------------------
 # flash attention
 
 
