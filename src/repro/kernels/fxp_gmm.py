@@ -70,29 +70,44 @@ def row_layout(group: Array, groups: int, tile: int
     [0, groups) or ``groups`` for a row that goes to no group here.
 
     Returns ({"dest": (A,) buffer row of each entry (M for those of no
-    group), "sizes": (G,) rows each group spans in the buffer, and the
+    group), "asg": (M,) entry of each buffer row (A for padding and dead
+    rows), "sizes": (G,) rows each group spans in the buffer, and the
     kernels' LAYOUT_KEYS, "rows" among them: (G,) rows per group}, M, the
-    buffer's row count)."""
+    buffer's row count). ``dest`` and ``asg`` are inverse maps; both come
+    from the stable sort of ``group`` by sorts and gathers, with no
+    scatter."""
     A = group.shape[0]
     G = groups
-    counts_all = jnp.zeros((G + 1,), jnp.int32).at[group].add(1)
-    counts = counts_all[:G]
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    sorted_g = group[order]
+    # first[g]: where group g's entries start in sorted order; first[G]
+    # those of no group, first[G + 1] = A
+    first = jnp.searchsorted(sorted_g, jnp.arange(G + 2, dtype=group.dtype),
+                             method="compare_all").astype(jnp.int32)
+    counts = first[1:G + 1] - first[:G]
     sizes = jnp.maximum(-(-counts // tile), 1) * tile
     M = -(-A // tile) * tile + G * tile
     start = jnp.cumsum(sizes) - sizes
-    order = jnp.argsort(group, stable=True)
-    sorted_g = group[order]
-    first = jnp.cumsum(counts_all) - counts_all           # in sorted order
     rank = jnp.arange(A, dtype=jnp.int32) - first[sorted_g]
     held = sorted_g < G
     dest_sorted = jnp.where(
         held, jnp.take(start, jnp.minimum(sorted_g, G - 1)) + rank, M)
-    dest = jnp.zeros((A,), jnp.int32).at[order].set(dest_sorted)
+    # back to entry order: sort the sorted rows by the entry they hold
+    dest = jax.lax.sort((order, dest_sorted), num_keys=1)[1]
     tiles = jnp.arange(M // tile, dtype=jnp.int32) * tile
-    tg = jnp.searchsorted(start, tiles, side="right").astype(jnp.int32) - 1
+    tg = jnp.searchsorted(start, tiles, side="right",
+                          method="compare_all").astype(jnp.int32) - 1
+    tile_group = jnp.minimum(tg, G - 1)
+    # buffer row r of group g at rank q holds the entry sorted at
+    # first[g] + q while q < rows[g]; a dead tile's rows lie past the last
+    # group's span
+    row_g = jnp.repeat(tile_group, tile)
+    q = jnp.arange(M, dtype=jnp.int32) - start[row_g]
+    asg = jnp.where(q < counts[row_g],
+                    order[jnp.minimum(first[row_g] + q, A - 1)], A)
     live = (jnp.sum(sizes) // tile).reshape(1).astype(jnp.int32)
-    return {"dest": dest, "sizes": sizes, "start": start, "rows": counts,
-            "tile_group": jnp.minimum(tg, G - 1), "live": live}, M
+    return {"dest": dest, "asg": asg, "sizes": sizes, "start": start,
+            "rows": counts, "tile_group": tile_group, "live": live}, M
 
 
 def _row_mask(x: Array, t, tile: int, end) -> Array:

@@ -30,7 +30,14 @@ Two dispatches, picked by what the layer sees:
   fixed-point kernels (``kernels/ops.fxp_gmm``: int8 words, each expert's
   own FL); otherwise ``jax.lax.ragged_dot`` on the dequantized weights,
   over the same layout.
-  Routing, sort, gather and combine run under ``adapt.moe_route``.
+  The dispatch and the combine are a pair of permutations through
+  ``dest`` (assignment -> buffer row) and its inverse ``asg``, both from
+  ``fxp_gmm.row_layout``'s sorts and gathers: each one's transpose is a
+  gather through the other map (``_dispatch``, ``_combine``), so neither
+  direction scatters a row; the residuals are the int32 maps, the (T, k)
+  weights and the experts' output that the combine reads. Routing,
+  layout, dispatch and combine, and their transposes, run under
+  ``adapt.moe_route``.
 * capacity (sharding rules that split the batch or the experts over a
   mesh, as GSPMD lowers them): the GShard group-limited dispatch, whose
   (g, E, cap, D) buffer keeps the data and expert dims sharded; tokens past
@@ -38,6 +45,7 @@ Two dispatches, picked by what the layer sees:
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -125,23 +133,72 @@ def _apply_held(p, h, cfg: ModelConfig, use_pallas: bool):
         weights, chosen = route(tokens, p["router"], k)
         local = chosen.reshape(T * k) - off
         group = jnp.where((local >= 0) & (local < E), local, E)
-        layout, M = fxp_gmm.row_layout(group, E, tile)
-        # buffer row -> token; rows no assignment fills read a zero row
-        src = jnp.full((M + 1,), T, jnp.int32).at[layout["dest"]].set(
-            jnp.arange(T * k, dtype=jnp.int32) // k)[:M]
-        buf = jnp.concatenate([tokens, jnp.zeros((1, D), h.dtype)])[src]
+        layout, _ = fxp_gmm.row_layout(group, E, tile)
+        buf = _dispatch(k, tokens, layout["asg"], layout["dest"])
     gate = _experts(p["we_gate"], buf, layout, tile, use_pallas)
     up = _experts(p["we_up"], buf, layout, tile, use_pallas)
     act = (common.act_fn(gate, cfg.act_fn) * up).astype(h.dtype)
     eout = _experts(p["we_down"], act, layout, tile, use_pallas)
     with jax.named_scope("adapt.moe_route"):
-        rows = jnp.concatenate([eout, jnp.zeros((1, D), eout.dtype)]
-                               )[jnp.minimum(layout["dest"], M)]
-        out = jnp.sum(rows.reshape(T, k, D).astype(jnp.float32)
-                      * weights[..., None], axis=1)
+        out = _combine(eout, weights, layout["dest"], layout["asg"])
         counts = layout["rows"]
     return (out.reshape(B, S, D).astype(h.dtype),
             (jnp.sum(counts), jnp.max(counts)))
+
+
+def _rows(x: Array, idx: Array) -> Array:
+    """x[idx], zeros where idx is past x's rows."""
+    return x.at[idx].get(mode="fill", fill_value=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(k: int, tokens: Array, asg: Array, dest: Array) -> Array:
+    """buf[r] = tokens[asg[r] // k]: (T, D) -> (M, D)."""
+    return _rows(tokens, asg // k)
+
+
+def _dispatch_fwd(k, tokens, asg, dest):
+    return _dispatch(k, tokens, asg, dest), dest
+
+
+def _dispatch_bwd(k, dest, d_buf):
+    """d_tokens[t] = Σ_j d_buf[dest[t·k + j]], summed in f32."""
+    with jax.named_scope("adapt.moe_route"):
+        rows = _rows(d_buf, dest).astype(jnp.float32)
+        d_tokens = jnp.sum(rows.reshape(-1, k, d_buf.shape[1]), axis=1)
+    return d_tokens.astype(d_buf.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(eout: Array, weights: Array, dest: Array, asg: Array) -> Array:
+    """out[t] = Σ_j w[t, j] · eout[dest[t·k + j]]: (M, D) -> (T, D) f32."""
+    T, k = weights.shape
+    rows = _rows(eout, dest).reshape(T, k, eout.shape[1])
+    return jnp.sum(rows.astype(jnp.float32) * weights[..., None], axis=1)
+
+
+def _combine_fwd(eout, weights, dest, asg):
+    return _combine(eout, weights, dest, asg), (eout, weights, dest, asg)
+
+
+def _combine_bwd(res, d_out):
+    """d_eout[r] = w[a] · d_out[a // k] at a = asg[r], each assignment's
+    product rounded to eout's dtype before it is gathered into the
+    buffer's order; d_w[t, j] = ⟨d_out[t], eout[dest[t·k + j]]⟩."""
+    eout, weights, dest, asg = res
+    T, k = weights.shape
+    with jax.named_scope("adapt.moe_route"):
+        rows = _rows(eout, dest).reshape(T, k, eout.shape[1])
+        d_w = jnp.sum(d_out[:, None, :] * rows.astype(jnp.float32), axis=-1)
+        d_rows = (d_out[:, None, :] * weights[..., None]).astype(eout.dtype)
+        d_eout = _rows(d_rows.reshape(T * k, eout.shape[1]), asg)
+    return d_eout, d_w, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _apply_capacity(p, h, cfg: ModelConfig, dropless: bool):

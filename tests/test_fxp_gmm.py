@@ -1,7 +1,8 @@
 """The grouped fixed-point kernels (``kernels/fxp_gmm.py``) in interpret
 mode against plain ``jnp``: forward, dx and dw over rows sorted by expert,
 on layouts with balanced groups, an empty group, a single-row group, every
-row in one group, and row counts off the tile."""
+row in one group, and row counts off the tile; and the layout against the
+formula that scatters it."""
 import re
 
 import jax
@@ -70,6 +71,60 @@ def test_layout_places_every_held_row_once(case):
         assert np.all((rows >= start[g]) & (rows < start[g] + counts[g]))
     assert int(lay["live"][0]) * TILE == sizes.sum() <= M
     assert list(np.asarray(lay["rows"])) == counts[:G]
+
+
+def _scatter_layout(group, G, tile):
+    """The layout as scatters write it: counts by a scatter-add of ones,
+    ``dest`` by a scatter through the sort's permutation, and the buffer
+    row -> entry map by a scatter through ``dest``."""
+    A = group.shape[0]
+    counts_all = jnp.zeros((G + 1,), jnp.int32).at[group].add(1)
+    counts = counts_all[:G]
+    sizes = jnp.maximum(-(-counts // tile), 1) * tile
+    M = -(-A // tile) * tile + G * tile
+    start = jnp.cumsum(sizes) - sizes
+    order = jnp.argsort(group, stable=True)
+    sorted_g = group[order]
+    first = jnp.cumsum(counts_all) - counts_all
+    rank = jnp.arange(A, dtype=jnp.int32) - first[sorted_g]
+    held = sorted_g < G
+    dest_sorted = jnp.where(
+        held, jnp.take(start, jnp.minimum(sorted_g, G - 1)) + rank, M)
+    dest = jnp.zeros((A,), jnp.int32).at[order].set(dest_sorted)
+    tiles = jnp.arange(M // tile, dtype=jnp.int32) * tile
+    tg = jnp.searchsorted(start, tiles, side="right").astype(jnp.int32) - 1
+    live = (jnp.sum(sizes) // tile).reshape(1).astype(jnp.int32)
+    asg = jnp.full((M + 1,), A, jnp.int32).at[dest].set(
+        jnp.arange(A, dtype=jnp.int32))[:M]
+    return {"dest": dest, "asg": asg, "sizes": sizes, "start": start,
+            "rows": counts, "tile_group": jnp.minimum(tg, G - 1),
+            "live": live}, M
+
+
+# CASES, and: every row in no group; every row in one group; each group a
+# whole number of tiles
+LAYOUTS = dict(CASES, none_held=[0, 0, 0, 0, 21],
+               all_one_group=[0, 32, 0, 0, 0],
+               tile_multiples=[16, 8, 24, 8, 7])
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("case", sorted(LAYOUTS) + ["random"])
+def test_layout_equals_the_scatter_formula(case, seed):
+    """``row_layout``, made of sorts and gathers, returns what the scatter
+    formula does: every map, span and count, and M."""
+    if case == "random":
+        rng = np.random.default_rng(seed)
+        group = jnp.asarray(rng.integers(0, G + 1, 4 * TILE + 3), jnp.int32)
+    else:
+        group = _groups(LAYOUTS[case], seed)
+    got, M = jax.jit(fg.row_layout, static_argnums=(1, 2))(group, G, TILE)
+    want, M0 = _scatter_layout(group, G, TILE)
+    assert M == M0
+    assert sorted(got) == sorted(want)
+    for key in want:
+        np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+        assert got[key].dtype == want[key].dtype, key
 
 
 @pytest.mark.parametrize("case", sorted(CASES))

@@ -351,3 +351,182 @@ def test_full_remat_checkpoints_moe_periods_by_slot(arch):
     moe_period = any(slot.ffn == "moe" for slot in plan)
     assert jaxpr_tools.count_primitives(jaxpr, "remat") == \
         (len(plan) if moe_period else 1)
+
+
+# --- the held dispatch's routing: gathers both ways -------------------------
+
+def _gather_held(p, h, cfg, use_pallas):
+    """The held dispatch with plain gathers and autodiff (their transposes
+    are row scatter-adds): what ``moe._dispatch``/``moe._combine`` replace."""
+    from repro.kernels import fxp_gmm
+    B, S, D = h.shape
+    E, k, off = moe.held(cfg), cfg.experts_per_token, cfg.expert_offset
+    T = B * S
+    tokens = h.reshape(T, D)
+    tile = fxp_gmm.row_tile(T * k / cfg.num_experts)
+    weights, chosen = moe.route(tokens, p["router"], k)
+    local = chosen.reshape(T * k) - off
+    group = jnp.where((local >= 0) & (local < E), local, E)
+    layout, M = fxp_gmm.row_layout(group, E, tile)
+    src = jnp.full((M + 1,), T, jnp.int32).at[layout["dest"]].set(
+        jnp.arange(T * k, dtype=jnp.int32) // k)[:M]
+    buf = jnp.concatenate([tokens, jnp.zeros((1, D), h.dtype)])[src]
+    gate = moe._experts(p["we_gate"], buf, layout, tile, use_pallas)
+    up = moe._experts(p["we_up"], buf, layout, tile, use_pallas)
+    act = (common.act_fn(gate, cfg.act_fn) * up).astype(h.dtype)
+    eout = moe._experts(p["we_down"], act, layout, tile, use_pallas)
+    rows = jnp.concatenate([eout, jnp.zeros((1, D), eout.dtype)]
+                           )[jnp.minimum(layout["dest"], M)]
+    out = jnp.sum(rows.reshape(T, k, D).astype(jnp.float32)
+                  * weights[..., None], axis=1)
+    counts = layout["rows"]
+    return (out.reshape(B, S, D).astype(h.dtype),
+            (jnp.sum(counts), jnp.max(counts)))
+
+
+def _gather_apply(p, x, cfg, use_pallas):
+    h = common.rms_norm(x, p["pre_norm"], cfg.norm_eps)
+    out, counts = _gather_held(p, h, cfg, use_pallas)
+    return x + out, counts
+
+
+def _steer(p, x, favoured):
+    """Router and input such that every token's top-k is ``favoured``: a
+    large constant feature that the router reads for those experts alone."""
+    E = p["router"].shape[-1]
+    sign = jnp.where(jnp.isin(jnp.arange(E), jnp.asarray(favoured)), 4., -4.)
+    return (dict(p, router=p["router"].at[0].set(sign)),
+            x.at[..., 0].set(8.0))
+
+
+# (held, offset, k, favoured experts or None for the seeded router):
+# rows of absent experts present; no held rows; every held row on one
+# expert; one expert per token
+ROUTES = {
+    "absent_experts": (4, 2, 2, None),
+    "none_held": (4, 2, 2, (0, 7)),
+    "one_held_expert": (4, 2, 2, (3, 0)),
+    "k1": (4, 2, 1, None),
+}
+
+
+def _route_case(case, dtype, use_pallas):
+    held, off, k, fav = ROUTES[case]
+    m = dataclasses.replace(_model(held, off), experts_per_token=k)
+    p, x = _layer_params(m), _x(seed=5)
+    if fav is not None:
+        p, x = _steer(p, x, fav)
+    q = _packed(p) if use_pallas else p
+    return m, q, x.astype(dtype)
+
+
+def _expert_leaves(q, use_pallas):
+    return {n: (q[n]["wref"] if use_pallas else q[n])
+            for n in fxp.EXPERT_PARAM_NAMES}
+
+
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["ragged_dot", "grouped_kernels"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(ROUTES))
+def test_routing_vjp_matches_plain_gathers(case, dtype, use_pallas):
+    """The held dispatch against the gather-and-autodiff formulation: the
+    forward and the routing counts bitwise; the cotangents of the router
+    and of the expert receivers (fed by the combine's transpose) bitwise;
+    that of x, whose dispatch transpose sums a token's k rows in f32 and
+    rounds once, within 1e-6 in f32 and k bf16 roundings in bf16."""
+    m, q, x = _route_case(case, dtype, use_pallas)
+    r = jax.random.normal(jax.random.PRNGKey(13), x.shape)
+
+    def loss(apply):
+        def f(x, router, w):
+            qq = dict(q, router=router)
+            for n, leaf in w.items():
+                qq[n] = dict(q[n], wref=leaf) if use_pallas else leaf
+            out, _ = apply(qq, x, m, use_pallas)
+            return jnp.sum(out.astype(jnp.float32) * r)
+        return jax.grad(f, argnums=(0, 1, 2))
+
+    def program(p, x, cfg, use_pallas):
+        return moe.apply(p, x, cfg, use_pallas=use_pallas)
+
+    got, (rows, largest) = program(q, x, m, use_pallas)
+    want, (rows0, largest0) = _gather_apply(q, x, m, use_pallas)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert (int(rows), int(largest)) == (int(rows0), int(largest0))
+    T, k = x.shape[0] * x.shape[1], m.experts_per_token
+    if case == "none_held":
+        assert int(rows) == 0
+    elif case == "one_held_expert":
+        assert int(rows) == int(largest) == T
+    else:
+        assert 0 < int(largest) < int(rows) < T * k
+
+    args = (x, q["router"], _expert_leaves(q, use_pallas))
+    gx, grouter, gw = loss(program)(*args)
+    wx, wrouter, ww = loss(_gather_apply)(*args)
+    np.testing.assert_array_equal(grouter, wrouter)
+    for n in gw:
+        np.testing.assert_array_equal(np.asarray(gw[n], np.float32),
+                                      np.asarray(ww[n], np.float32),
+                                      err_msg=n)
+    gx, wx = np.asarray(gx, np.float32), np.asarray(wx, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(gx, wx, rtol=1e-6, atol=1e-6)
+    else:
+        tol = k * 2.0 ** -8
+        np.testing.assert_allclose(gx, wx, rtol=tol,
+                                   atol=tol * np.abs(wx).max())
+
+
+def _scatters(use_pallas):
+    """(primitive, operand shape) of every scatter in the jaxpr of the
+    gradient of a layer holding experts 2-5 of 8."""
+    m = _model(held=4, offset=2)
+    p = _layer_params(m)
+    q = _packed(p) if use_pallas else p
+    x = _x()
+
+    def f(q, x):
+        return jnp.sum(moe.apply(q, x, m, use_pallas=use_pallas)[0])
+
+    jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1), allow_int=True))(
+        q, x).jaxpr
+    return m, x, [(e.primitive.name, e.invars[0].aval.shape)
+                  for e in jaxpr_tools.iter_eqns(jaxpr)
+                  if "scatter" in e.primitive.name]
+
+
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["ragged_dot", "grouped_kernels"])
+def test_routing_gradient_scatters_no_rows(use_pallas):
+    """Neither the held dispatch nor its layout scatters: no scatter into
+    a D-wide operand (the dispatch and combine transposes are gathers),
+    none into the layout's counts or maps; the one left is top-k's
+    transpose into the (T, router experts) logits."""
+    m, x, scatters = _scatters(use_pallas)
+    B, S, D = x.shape
+    assert not [s for s in scatters if s[1][-1:] == (D,)], scatters
+    assert not [s for s in scatters if len(s[1]) == 1], scatters
+    assert scatters == [("scatter-add", (B * S, m.num_experts))]
+
+
+def test_routing_backward_keeps_the_route_scope():
+    """The rules of the dispatch and combine transposes run under
+    ``adapt.moe_route``, so their ops stay in ``moe.route_ms``."""
+    import re
+    m = _model(held=4, offset=2)
+    p, x = _layer_params(m), _x()
+
+    def f(p, x):
+        with jax.named_scope("adapt.layers"):
+            return jnp.sum(moe.apply(p, x, m)[0])
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1))).lower(p, x).compile(
+    ).as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    back = [n for n in names if n.startswith("jit(f)/transpose(")
+            and n.endswith("/gather")]
+    assert back and all("adapt.moe_route" in n for n in back), back
